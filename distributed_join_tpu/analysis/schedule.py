@@ -92,15 +92,15 @@ class ProgramSchedule:
 
 def _subjaxprs(eqn):
     """Inner jaxprs of one eqn (pjit/shard_map/scan/while/cond/...)."""
-    import jax
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     out = []
     for v in eqn.params.values():
         vals = v if isinstance(v, (tuple, list)) else (v,)
         for x in vals:
-            if isinstance(x, jax.core.ClosedJaxpr):
+            if isinstance(x, ClosedJaxpr):
                 out.append(x.jaxpr)
-            elif isinstance(x, jax.core.Jaxpr):
+            elif isinstance(x, Jaxpr):
                 out.append(x)
     return out
 
@@ -117,7 +117,9 @@ def collective_sequence(jaxpr) -> List[str]:
     jaxpr. Trace order is program order for collectives: XLA may
     overlap them with compute but never reorders collectives against
     each other without an explicit schedule pass."""
-    return [e.primitive.name for e in _walk_eqns(jaxpr)
+    # An invariant-typed all_gather is the same all-gather on the wire.
+    return [e.primitive.name.removesuffix("_invariant")
+            for e in _walk_eqns(jaxpr)
             if is_collective_prim(e.primitive.name)]
 
 
@@ -136,9 +138,9 @@ def cond_divergences(jaxpr) -> List[str]:
         branches = eqn.params.get("branches", ())
         seqs = []
         for br in branches:
-            import jax
+            from jax.extend.core import ClosedJaxpr
 
-            j = br.jaxpr if isinstance(br, jax.core.ClosedJaxpr) else br
+            j = br.jaxpr if isinstance(br, ClosedJaxpr) else br
             seqs.append(tuple(collective_sequence(j)))
         if len(set(seqs)) > 1:
             bad.append(

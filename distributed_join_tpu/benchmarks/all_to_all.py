@@ -6,8 +6,8 @@ The reference allocates fixed-size send/recv buffers per peer, loops
 the communication layer entirely. Here the isolated layer is the
 ``Communicator.all_to_all`` collective (XLA ``AllToAll`` over ICI on a
 real slice; the host-platform emulation on the CPU fake backend), timed
-with the chained-loop protocol so per-call RPC latency doesn't pollute
-the number.
+with the chained-loop protocol so per-call dispatch latency doesn't
+pollute the number.
 
 Bandwidth definition: per-rank egress — each rank sends
 ``(n_ranks - 1) / n_ranks`` of its buffer off-chip per iteration (the

@@ -47,7 +47,6 @@ import functools
 
 import jax
 
-from distributed_join_tpu import compat
 import jax.numpy as jnp
 
 from distributed_join_tpu.ops.expand_pallas import (
@@ -160,13 +159,13 @@ def stream_compact(mask: jax.Array, pos: jax.Array, cols, capacity: int,
     q = offs[:-1] - base[:-1] * 128
 
     out_pad = _round_up(capacity, 128) + w
-    vma = getattr(compat.typeof(vT), "vma", None)
+    vma = getattr(jax.typeof(vT), "vma", None)
     out_shape = (
         jax.ShapeDtypeStruct((ck, out_pad), jnp.float32, vma=vma)
         if vma is not None
         else jax.ShapeDtypeStruct((ck, out_pad), jnp.float32)
     )
-    with compat.enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(
                 _compact_kernel, block=block, chunk=chunk, ck=ck, w=w

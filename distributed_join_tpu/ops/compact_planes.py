@@ -38,7 +38,6 @@ import functools
 
 import jax
 
-from distributed_join_tpu import compat
 import jax.numpy as jnp
 from jax import lax
 
@@ -210,13 +209,13 @@ def plane_compact_stacked(stacked: jax.Array, mask: jax.Array,
     ins3d = full.reshape(P2, nblocks * RB, 128)
 
     out_rows = _round_up(capacity, 1024) // 128 + RS + 8
-    vma = getattr(compat.typeof(ins3d), "vma", None)
+    vma = getattr(jax.typeof(ins3d), "vma", None)
     out_sds = (
         jax.ShapeDtypeStruct((P, out_rows, 128), jnp.uint32, vma=vma)
         if vma is not None else
         jax.ShapeDtypeStruct((P, out_rows, 128), jnp.uint32)
     )
-    with compat.enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(
                 _compact_kernel, block=block, nplanes=P

@@ -89,7 +89,6 @@ from typing import Optional, Sequence
 
 import jax
 
-from distributed_join_tpu import compat
 import jax.numpy as jnp
 from jax import lax
 
@@ -632,7 +631,7 @@ def _expand_gather_b8(S, cols, out_capacity, block, interpret, lo,
     w2 = lo_pad[jnp.minimum(r0 + 1, S.shape[0] - 1)]
     w2a = jnp.clip(w2, 0, omax * 128) // 128
 
-    vma = getattr(compat.typeof(v8T), "vma", None)
+    vma = getattr(jax.typeof(v8T), "vma", None)
 
     # Output TILING (round 4): the f32 chunk-row output costs ~32 B
     # per u64 lane per output row; at spec-scale capacities one
@@ -653,7 +652,7 @@ def _expand_gather_b8(S, cols, out_capacity, block, interpret, lo,
         # x64 scoped off around the pallas_call ONLY: Mosaic fails to
         # legalize with global x64, but the u64 merge must see real
         # 64-bit types or it silently truncates to u32.
-        with compat.enable_x64(False):
+        with jax.enable_x64(False):
             return pl.pallas_call(
                 functools.partial(
                     _expand_kernel_b8, block=block, chunk=chunk,
@@ -800,7 +799,7 @@ def expand_gather(S: jax.Array, cols: Sequence[jax.Array],
 
     # Under shard_map with vma checking, the out_shape must carry how
     # the output varies over mesh axes — same as the inputs.
-    vma = getattr(compat.typeof(vT), "vma", None)
+    vma = getattr(jax.typeof(vT), "vma", None)
     # Output TILING (ADVICE r4): same scheme as the build wrapper — a
     # monolithic (ck, out_pad) f32 buffer exceeds HBM at spec-scale
     # capacities, and this wrapper serves the lax.cond fallback branch
@@ -824,7 +823,7 @@ def expand_gather(S: jax.Array, cols: Sequence[jax.Array],
         # offsets ride a plain SMEM input + manual DMA because
         # PrefetchScalarGridSpec also fails to legalize with this
         # toolchain.
-        with compat.enable_x64(False):
+        with jax.enable_x64(False):
             return pl.pallas_call(
                 functools.partial(
                     _expand_kernel, block=block, chunk=chunk,

@@ -41,7 +41,6 @@ import functools
 
 import jax
 
-from distributed_join_tpu import compat
 import jax.numpy as jnp
 from jax import lax
 
@@ -244,7 +243,7 @@ def expand_pull(S: jax.Array, cols, out_capacity: int,
         args = [rbase, roff, bb, boff, rec3d, b3d]
 
     nout = nrec + nbuild
-    vma = getattr(compat.typeof(rec3d), "vma", None)
+    vma = getattr(jax.typeof(rec3d), "vma", None)
     out_sds = (
         jax.ShapeDtypeStruct((nout, out_pad // 128, 128), jnp.uint32,
                              vma=vma)
@@ -255,7 +254,7 @@ def expand_pull(S: jax.Array, cols, out_capacity: int,
     if build_cols is not None:
         scratch.append(pltpu.VMEM((nbuild, RW, 128), jnp.uint32))
     scratch.append(pltpu.SemaphoreType.DMA((2,)))
-    with compat.enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(
                 _expand_kernel, block=block, nrec=nrec, nbuild=nbuild

@@ -72,7 +72,6 @@ from typing import Optional, Sequence
 
 import jax
 
-from distributed_join_tpu import compat
 import jax.numpy as jnp
 from jax import lax
 
@@ -207,7 +206,7 @@ def _expand_records(S, recs: dict, out_capacity: int, j, cfg):
     """
     use_pallas, interpret = cfg.expand_enabled()
     if use_pallas and interpret and getattr(
-        compat.typeof(S), "vma", None
+        jax.typeof(S), "vma", None
     ):
         # The Mosaic lowering works under shard_map on real TPU
         # (compile-checked: tpu_custom_call in the mesh module); only
@@ -314,7 +313,7 @@ def _kernel_path_ok(build, probe, keys, b1d, p1d, nb, npr,
     if not use:
         return False, False
     if interpret and getattr(
-        compat.typeof(build.columns[keys[0]]), "vma", None
+        jax.typeof(build.columns[keys[0]]), "vma", None
     ):
         # shard_map's interpreter trips on pallas_call vma checks; the
         # CPU test mesh runs the XLA pipeline instead (real-TPU
@@ -322,13 +321,27 @@ def _kernel_path_ok(build, probe, keys, b1d, p1d, nb, npr,
         return False, False
     if not (0 < nb and npr > 0 and nb + npr < 2**31 - 2
             and out_capacity < 2**31 - 2):
+        _warn_xla_fallback(interpret, f"sides of {nb} + {npr} rows "
+                           f"into {out_capacity} output slots")
         return False, False
     dts = (
         [build.columns[k].dtype for k in keys]
         + [build.columns[nm].dtype for nm in b1d]
         + [probe.columns[nm].dtype for nm in p1d]
     )
-    return all(_u64_lane_ok(dt) for dt in dts), interpret
+    bad = sorted({str(dt) for dt in dts if not _u64_lane_ok(dt)})
+    if bad:
+        _warn_xla_fallback(interpret, f"column dtypes {bad}")
+        return False, False
+    return True, interpret
+
+
+def _warn_xla_fallback(interpret: bool, why: str) -> None:
+    """The kernel pipeline was asked for but the data rules it out:
+    say so (once per reason), except under the test interpreter."""
+    if not interpret:
+        warnings.warn(f"join runs the XLA pipeline, not the Pallas "
+                      f"kernels: {why}", stacklevel=3)
 
 
 

@@ -30,7 +30,7 @@ import dataclasses
 import os
 from typing import Optional
 
-import jax
+from distributed_join_tpu import device
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,9 +69,10 @@ class KernelConfig:
     # -- resolution helpers (the ONE dispatch site) -------------------
 
     def expand_enabled(self) -> tuple[bool, bool]:
-        """(use_pallas_kernels, interpret). auto = real TPU only;
-        'pallas' forces the interpreter elsewhere."""
-        on_tpu = jax.default_backend() == "tpu"
+        """(use_pallas_kernels, interpret). auto = real TPU only
+        (:func:`..device.on_tpu`); 'pallas' forces the interpreter
+        elsewhere."""
+        on_tpu = device.on_tpu()
         if self.expand == "xla":
             return False, False
         if self.expand == "pallas":

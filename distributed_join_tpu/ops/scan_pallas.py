@@ -51,7 +51,6 @@ from __future__ import annotations
 
 import jax
 
-from distributed_join_tpu import compat
 import jax.numpy as jnp
 
 from distributed_join_tpu.ops.expand_pallas import _round_up
@@ -245,7 +244,7 @@ def join_scans(tag: jax.Array, first: jax.Array,
 
     spec = pl.BlockSpec((8, L), lambda i: (i, 0))
     rspec = pl.BlockSpec((8, L), lambda i: (nblocks - 1 - i, 0))
-    vma = getattr(compat.typeof(tag2), "vma", None)
+    vma = getattr(jax.typeof(tag2), "vma", None)
 
     def _shape():
         if vma is not None:
@@ -254,7 +253,7 @@ def join_scans(tag: jax.Array, first: jax.Array,
             )
         return jax.ShapeDtypeStruct((n_pad // L, L), jnp.int32)
 
-    with compat.enable_x64(False):
+    with jax.enable_x64(False):
         matched2 = pl.pallas_call(
             _scan_r_kernel,
             grid=(nblocks,),

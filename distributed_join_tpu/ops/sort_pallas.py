@@ -61,7 +61,6 @@ from typing import Sequence
 
 import jax
 
-from distributed_join_tpu import compat
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -439,7 +438,7 @@ def _merge_level(stacked, a0, b0, p, dirs,
     ntiles = a0.shape[0]
 
     ins3d = stacked.reshape(P, rows, 128)
-    vma = getattr(compat.typeof(ins3d), "vma", None)
+    vma = getattr(jax.typeof(ins3d), "vma", None)
 
     def sds(shape, dt):
         if vma is not None:
@@ -463,7 +462,7 @@ def _merge_level(stacked, a0, b0, p, dirs,
     aoff = a0 - abase * 128
     bbase = jnp.minimum((b0 // 1024) * 8, bound)
     boff = b0 - bbase * 128
-    with compat.enable_x64(False):
+    with jax.enable_x64(False):
         out = pl.pallas_call(
             functools.partial(
                 _merge_tile_kernel, tile=tile, nplanes=P, nkeys=nkeys

@@ -1826,13 +1826,13 @@ def main(argv=None) -> int:
     # The soak is a CPU-mesh harness by design (deterministic,
     # hardware-free); reuse the shared platform forcing + the
     # persistent compile cache so repeat soaks replay their programs.
+    from distributed_join_tpu import device
     from distributed_join_tpu.benchmarks import force_cpu_platform
 
     force_cpu_platform(args.n_ranks)
     import jax
 
-    jax.config.update("jax_compilation_cache_dir",
-                      "/tmp/djtpu_jax_cache")
+    device.enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       0.5)
 

@@ -34,6 +34,7 @@ mesh, with row-sharded inputs/outputs; the orchestrator
 from __future__ import annotations
 
 import abc
+import functools
 from typing import Callable
 
 import jax
@@ -41,11 +42,24 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from distributed_join_tpu import compat
+from distributed_join_tpu import device
 from distributed_join_tpu.parallel.mesh import (
     make_hierarchical_mesh,
     make_mesh,
 )
+
+
+# The TPU ragged-all-to-all pads each moved row to this many lanes.
+_RAGGED_LANES = 128
+
+
+def _pvary(x, axis_name):
+    """Mark ``x`` varying over ``axis_name`` for shard_map's vma
+    checker; already-varying inputs pass through (``lax.pcast``
+    refuses a varying -> varying cast)."""
+    if axis_name in jax.typeof(x).vma:
+        return x
+    return lax.pcast(x, axis_name, to="varying")
 
 
 class Communicator(abc.ABC):
@@ -78,6 +92,12 @@ class Communicator(abc.ABC):
         replicated to all ranks. Must be called inside :meth:`spmd`.
         The skew path broadcasts heavy-hitter build rows with this —
         the TPU analog of the reference replicating hot partitions."""
+
+    def all_gather_replicated(self, x: jax.Array) -> jax.Array:
+        """:meth:`all_gather` whose result shard_map's vma checker
+        knows to be replicated, so it may leave the program through a
+        replicated output (the telemetry tape)."""
+        return self.all_gather(x)
 
     @abc.abstractmethod
     def spmd(self, fn: Callable, *, sharded_out=None) -> Callable:
@@ -205,6 +225,14 @@ class TpuCommunicator(Communicator):
     def all_gather(self, x: jax.Array) -> jax.Array:
         return lax.all_gather(x, self.axis_name, axis=0, tiled=True)
 
+    def all_gather_replicated(self, x: jax.Array) -> jax.Array:
+        # The same all-gather HLO; JAX 0.9 exports no public spelling
+        # of the invariant-typed form.
+        from jax._src.lax.parallel import all_gather_invariant
+
+        return all_gather_invariant(x, self.axis_name, axis=0,
+                                    tiled=True)
+
     def ppermute_all_to_all(self, x: jax.Array) -> jax.Array:
         """``all_to_all`` semantics via a chain of n-1
         ``collective-permute`` steps (plus the local block).
@@ -244,29 +272,31 @@ class TpuCommunicator(Communicator):
         return lax.axis_index(self.axis_name)
 
     def pvary(self, x):
-        return compat.pvary(x, self.axis_name)
+        return _pvary(x, self.axis_name)
 
     def ragged_all_to_all(self, operand, output, input_offsets,
                           send_sizes, output_offsets, recv_sizes):
-        if jax.default_backend() != "tpu":
+        plan = (input_offsets, send_sizes, output_offsets, recv_sizes)
+        if not device.on_tpu():
             # XLA:CPU has no ragged-all-to-all thunk.
-            return self._ragged_emulate(
-                operand, output, input_offsets, send_sizes,
-                output_offsets, recv_sizes,
-            )
+            return self._ragged_emulate(operand, output, *plan)
+        return self._ragged_tpu(
+            operand, output, plan,
+            functools.partial(lax.ragged_all_to_all,
+                              axis_name=self.axis_name))
+
+    def _ragged_tpu(self, operand, output, plan, exchange):
+        """Route one column through the TPU's ragged-all-to-all.
+
+        The op moves rows, and pads each row to 128 lanes: a 1-D
+        column's rows are single elements, so it would move and hold
+        128x the column (the 50M-row four-chip join ran out of HBM
+        compiling it, PR 21). 1-D columns therefore go through
+        :meth:`_ragged_lane_dense`; 64-bit integers ride as two uint32
+        words (the x64 rewriter has no 64-bit op) and narrow types as
+        int32. ``exchange`` is the raw op (the emulation in tests)."""
         dt = operand.dtype
-        if dt.itemsize == 8 and jnp.issubdtype(dt, jnp.integer):
-            # The TPU x64 rewriter does not implement 64-bit
-            # ragged-all-to-all; integer bitcasts ARE implemented, so
-            # 64-bit integer operands ride as (rows, ..., 2) uint32.
-            u = lax.bitcast_convert_type(operand, jnp.uint32)
-            out_u = lax.bitcast_convert_type(output, jnp.uint32)
-            res = lax.ragged_all_to_all(
-                u, out_u, input_offsets, send_sizes,
-                output_offsets, recv_sizes, axis_name=self.axis_name,
-            )
-            return lax.bitcast_convert_type(res, dt)
-        if dt.itemsize == 8:
+        if dt.itemsize == 8 and jnp.issubdtype(dt, jnp.floating):
             # f64: neither the 64-bit op nor an f64 bitcast exists on
             # TPU. The emulation is correct but all-gathers the whole
             # column — MORE wire bytes than the padded shuffle; warn
@@ -278,16 +308,80 @@ class TpuCommunicator(Communicator):
                 "all-gather emulation on TPU, which moves MORE bytes "
                 "than the padded shuffle; keep f64 columns on "
                 "shuffle='padded'",
-                stacklevel=2,
+                stacklevel=3,
             )
-            return self._ragged_emulate(
-                operand, output, input_offsets, send_sizes,
-                output_offsets, recv_sizes,
-            )
-        return lax.ragged_all_to_all(
-            operand, output, input_offsets, send_sizes,
-            output_offsets, recv_sizes, axis_name=self.axis_name,
-        )
+            return self._ragged_emulate(operand, output, *plan)
+        if dt.itemsize == 8:
+            u = operand.astype(jnp.uint64)
+            out_u = output.astype(jnp.uint64)
+            words = [
+                self._ragged_tpu(
+                    (u >> jnp.uint64(s)).astype(jnp.uint32),
+                    (out_u >> jnp.uint64(s)).astype(jnp.uint32),
+                    plan, exchange,
+                ).astype(jnp.uint64) << jnp.uint64(s)
+                for s in (0, 32)
+            ]
+            return (words[0] | words[1]).astype(dt)
+        if operand.ndim > 1:
+            # Row = the trailing dims: lane-padded only where they are
+            # narrower than 128 (the fixed-width string columns).
+            return exchange(operand, output, *plan)
+        if dt.itemsize < 4:
+            return self._ragged_tpu(
+                operand.astype(jnp.int32), output.astype(jnp.int32),
+                plan, exchange,
+            ).astype(dt)
+        return self._ragged_lane_dense(operand, output, plan, exchange)
+
+    def _ragged_lane_dense(self, operand, output, plan, exchange):
+        """A 1-D 32-bit ragged exchange through (groups, 128) rows.
+
+        Every (sender, destination) block starts on a 128-element
+        boundary of the send and receive buffers, so the op moves whole
+        lane-dense rows; at most 127 pad elements ride per block. The
+        blocks move into and out of that layout with one rolled copy
+        per peer (a shift, not a gather)."""
+        input_offsets, send_sizes, output_offsets, _ = plan
+        lanes = _RAGGED_LANES
+        n = self.n_ranks
+        me = self.axis_index()
+        dt = operand.dtype
+        # Every sender's sizes and output offsets (row = sender): the
+        # receiver's block positions follow from the sizes alone.
+        g = self.all_gather(
+            jnp.concatenate([send_sizes, output_offsets])[None, :])
+        sizes, offs = g[:, :n], g[:, n:]
+        groups = (sizes + (lanes - 1)) // lanes
+        at = jnp.cumsum(groups, axis=0) - groups   # [s, d] in groups
+        g_send = groups[me]
+        a_send = jnp.cumsum(g_send) - g_send
+
+        n_send = -(-operand.shape[0] // lanes) + n
+        x = jnp.pad(operand, (0, n_send * lanes - operand.shape[0]))
+        pos = jnp.arange(n_send * lanes, dtype=jnp.int32)
+        send = jnp.zeros_like(x)
+        for d in range(n):
+            start = a_send[d] * lanes
+            keep = (pos >= start) & (pos < start + send_sizes[d])
+            send = jnp.where(keep, jnp.roll(x, start - input_offsets[d]),
+                             send)
+        n_recv = -(-output.shape[0] // lanes) + n
+        recv = exchange(
+            send.reshape(n_send, lanes),
+            self.pvary(jnp.zeros((n_recv, lanes), dt)),
+            a_send, g_send, at[me], groups[:, me],
+        ).reshape(-1)
+
+        m = output.shape[0]
+        pos = jnp.arange(m, dtype=jnp.int32)
+        out = output
+        for s in range(n):
+            o, r = offs[s, me], sizes[s, me]
+            keep = (pos >= o) & (pos < o + r)
+            out = jnp.where(keep, jnp.roll(recv, o - at[s, me] * lanes)[:m],
+                            out)
+        return out
 
     def psum(self, x):
         return lax.psum(x, self.axis_name)
@@ -301,7 +395,7 @@ class TpuCommunicator(Communicator):
                 lambda rep: P() if rep else shard_spec,
                 sharded_out,
             )
-        mapped = compat.shard_map(
+        mapped = jax.shard_map(
             fn, mesh=self.mesh, in_specs=shard_spec, out_specs=out_specs
         )
         return jax.jit(mapped)
@@ -386,8 +480,7 @@ class HierarchicalTpuCommunicator(TpuCommunicator):
                 + lax.axis_index(self.chip_axis))
 
     def pvary(self, x):
-        return compat.pvary(
-            compat.pvary(x, self.slice_axis), self.chip_axis)
+        return _pvary(_pvary(x, self.slice_axis), self.chip_axis)
 
     def ppermute_all_to_all(self, x: jax.Array) -> jax.Array:
         """The 1-D ppermute chain is a flat-mesh lowering; on the

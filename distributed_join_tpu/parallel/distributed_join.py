@@ -306,7 +306,7 @@ def make_join_step(
     separately from :func:`make_distributed_join` so harnesses can wrap
     extra structure around the step before compiling — e.g. ``bench.py``
     chains K dependent steps in one ``lax.fori_loop`` for honest timing
-    over this environment's RPC relay.
+    (``utils/benchmarking.py``).
 
     Static capacities (the XLA dynamic-shape answer, SURVEY.md §7):
     - shuffle pad per (batch, destination) bucket =

@@ -248,6 +248,9 @@ class FaultInjectingCommunicator(Communicator):
     def pvary(self, x):
         return self._inner.pvary(x)
 
+    def all_gather_replicated(self, x):
+        return self._inner.all_gather_replicated(x)
+
     def psum(self, x):
         return self._inner.psum(x)
 

@@ -557,6 +557,15 @@ def in_process_fleet_factory(n_replicas: int, ranks_per_replica: int,
     return factory
 
 
+class ChipSharingError(ValueError):
+    """A fleet whose replica processes would share one host's chips.
+
+    A chip belongs to one process at a time: a second replica process
+    on the same TPU host fails or hangs at spawn. Serve several
+    replicas there in one process (``in_process_fleet_factory``, each
+    on its own devices)."""
+
+
 def process_fleet_factory(config: FleetConfig,
                           platform: str = "cpu",
                           extra_args: Optional[list] = None,
@@ -572,7 +581,19 @@ def process_fleet_factory(config: FleetConfig,
     and ``"persist": False`` (exclude the victim from the shared
     persist dir, so a corruption-armed replica must TRACE — a
     corrupted trace must never enter the fleet's distribution
-    tier)."""
+    tier).
+
+    Every platform but ``cpu`` runs the replicas on the host's chips,
+    so a fleet that may hold more than one replica process (base
+    replicas or the autoscaler's ceiling) raises
+    :class:`ChipSharingError` here, before anything spawns."""
+    most = max(config.n_replicas,
+               config.autoscale_max_replicas if config.autoscale else 0)
+    if platform != "cpu" and most > 1:
+        raise ChipSharingError(
+            f"platform {platform!r}: up to {most} replica processes "
+            "would share this host's chips (one process per chip); "
+            "use in-process replicas or --platform cpu")
 
     def factory(index: int, generation: int) -> ProcessReplica:
         override = ((replica_overrides or {}).get(index) or {}

@@ -427,6 +427,13 @@ class JoinProgramCache:
         return CachedProgram(sig, raw, with_aux, "trace",
                              persisted=persisted)
 
+    def _devices(self):
+        """The mesh a blob must load onto (None: every device). A
+        replica on a subset of the host's devices would otherwise get
+        the executable loaded across all of them."""
+        mesh = getattr(self.comm, "mesh", None)
+        return None if mesh is None else list(mesh.devices.flat)
+
     def _blob_path(self, sig: JoinSignature) -> str:
         return os.path.join(self.persist_dir,
                             sig.digest() + PROGRAM_SUFFIX)
@@ -468,7 +475,8 @@ class JoinProgramCache:
         # Loadability check NOW, not at restart: a blob that cannot
         # deserialize is a silent trace-per-restart, the exact cost
         # this tier exists to remove.
-        serialize_executable.deserialize_and_load(*blob)
+        serialize_executable.deserialize_and_load(
+            *blob, execution_devices=self._devices())
         os.makedirs(self.persist_dir, exist_ok=True)
         path = self._blob_path(sig)
         payload = {
@@ -502,7 +510,7 @@ class JoinProgramCache:
                 self.disk_load_failures += 1
                 return None
             raw = serialize_executable.deserialize_and_load(
-                *payload["program"])
+                *payload["program"], execution_devices=self._devices())
         except Exception as exc:
             # A stale blob (jaxlib bump, different device topology) is
             # a cache miss, not an outage.

@@ -59,7 +59,7 @@ LINEITEM_DTYPES = {
 }
 
 #: H2D staging was the measured SF-100 bottleneck (305 s of 544 s at
-#: ~50-140 MB/s over this environment's relay — BASELINE.md config 4);
+#: ~50-140 MB/s over the pre-PR-21 relay — BASELINE.md config 4);
 #: every generated value fits int32 whenever the sparse orderkeys
 #: ((i//8)*32 + i%8 + 1 ~ 4*n_orders = 6M*SF) stay < 2^31 — SF up to
 #: ~357 (o_totalprice < 55.55M and l_extendedprice < 10.5M always

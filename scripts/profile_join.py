@@ -4,11 +4,9 @@ Times each stage of ops/join.py's merged-sort core in isolation so the
 optimization target is measured, not guessed (VERDICT round 1, weak #1:
 "no profile exists to even localize the time").
 
-Uses the chained-fori_loop protocol from utils/benchmarking.py — on this
-environment's RPC relay, per-call block_until_ready timing lies (it
-returned 0.1 ms for a join that takes ~600 ms), so each primitive is
-run ITERS dependent times inside one compiled loop, perturbed by the
-loop counter, reduced to one scalar.
+Uses the chained-fori_loop protocol from utils/benchmarking.py: each
+primitive is run ITERS dependent times inside one compiled loop,
+perturbed by the loop counter, reduced to one scalar.
 
 Run: PYTHONPATH=/root/repo:$PYTHONPATH python scripts/profile_join.py
 """

@@ -201,11 +201,10 @@
 #                                   # byte-identity gates)
 #
 # Notes:
-# - tests/conftest.py points the persistent XLA compile cache at
-#   /tmp/djtpu_jax_cache; a cold cache pays ~8-device compiles for
-#   every shard_map program, a warm one replays them. CI images that
-#   wipe /tmp should run the faults lane first to warm the hot
-#   programs, or persist the cache dir between runs.
+# - the persistent XLA compile cache lives where
+#   JAX_COMPILATION_CACHE_DIR says, else in the checkout's .jax_cache/
+#   (distributed_join_tpu/device.py); a cold cache pays ~8-device
+#   compiles for every shard_map program, a warm one replays them.
 set -u
 cd "$(dirname "$0")/.."
 
@@ -245,7 +244,6 @@ case "$lane" in
     tmp="$(mktemp -d /tmp/djtpu_explain.XXXXXX)"
     trap 'rm -rf "$tmp"' EXIT
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.benchmarks.distributed_join \
       --platform cpu --n-ranks 8 \
       --build-table-nrows 8000 --probe-table-nrows 8000 \
@@ -279,7 +277,6 @@ case "$lane" in
     timeout -k 10 60 env JAX_PLATFORMS=cpu \
       python -m distributed_join_tpu.analysis.lint --contracts-only
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.benchmarks.distributed_join \
       --platform cpu --n-ranks 8 \
       --build-table-nrows 8000 --probe-table-nrows 8000 \
@@ -300,7 +297,6 @@ case "$lane" in
     # the strict batched-beats-sequential gate lives in the service
     # lane.
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.service.server --smoke \
       --smoke-no-wall-gate --platform cpu --n-ranks 8 \
       --telemetry "$tmp/svc_tel" \
@@ -329,7 +325,6 @@ PY
     # match count — a changed router, codec, or tier split moves
     # them. The per-tier EXACT gate itself lives in the hier lane.
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.benchmarks.distributed_join \
       --platform cpu --n-ranks 8 --slices 2 --shuffle hierarchical \
       --build-table-nrows 8000 --probe-table-nrows 8000 \
@@ -345,7 +340,6 @@ PY
     # resolution, or partials exchange moves them. The strict
     # speedup/oracle gates live in the agg lane.
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.benchmarks.distributed_join \
       --platform cpu --n-ranks 8 \
       --build-table-nrows 16000 --probe-table-nrows 16000 \
@@ -366,7 +360,6 @@ PY
     # batched join seam moves them. The strict oracle/trace gates
     # live in the sortpath lane.
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.benchmarks.distributed_join \
       --platform cpu --n-ranks 8 \
       --build-table-nrows 8000 --probe-table-nrows 8000 \
@@ -389,7 +382,6 @@ PY
     # them. The oracle/trace/wire-exact gates live in the query
     # lane.
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.benchmarks.tpch_join \
       --platform cpu --n-ranks 8 --query q3 --scale-factor 0.01 \
       --iterations 1 --json-output "$tmp/query_smoke.json"
@@ -403,7 +395,6 @@ PY
     # failover loop, or persist-dir distribution tier moves them.
     # The drain-latency / shed gates live in the fleet lane.
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.service.fleet --smoke \
       --platform cpu --replica-ranks 2 \
       --json-output "$tmp/fleet_smoke.json"
@@ -416,7 +407,6 @@ PY
     # warm-verified autoscale spawn — docs/FLEET.md "Multi-tenancy
     # & autoscaling"); its behavior gates live in the fleet lane.
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.service.fleet --tenant-smoke \
       --platform cpu --replica-ranks 2 \
       --json-output "$tmp/tenant_smoke.json"
@@ -429,7 +419,6 @@ PY
     # protocol moves them. The latency/ordering gates live in the
     # fleet_ha lane.
     timeout -k 10 900 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.service.fleet --ha-smoke \
       --platform cpu --replica-ranks 2 \
       --json-output "$tmp/fleet_ha_smoke.json"
@@ -446,7 +435,6 @@ PY
     # timeline assembler moves them. The hop/critical-path shape
     # gates live in the tracing lane.
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.service.fleet --tracing-smoke \
       --platform cpu --replica-ranks 2 \
       --json-output "$tmp/tracing_smoke.json"
@@ -475,7 +463,6 @@ PY
     tmp="$(mktemp -d /tmp/djtpu_agg.XXXXXX)"
     trap 'rm -rf "$tmp"' EXIT
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.benchmarks.distributed_join \
       --platform cpu --n-ranks 8 \
       --build-table-nrows 16000 --probe-table-nrows 16000 \
@@ -500,7 +487,6 @@ PY
       "$tmp/agg_smoke.json" --baseline agg_smoke
     # no exec: the EXIT trap must still clean $tmp
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.benchmarks.tpch_join \
       --platform cpu --n-ranks 8 --scale-factor 0.01 --q3-filters \
       --agg --iterations 1 --out-capacity-factor 3.0 \
@@ -536,7 +522,6 @@ PY
     tmp="$(mktemp -d /tmp/djtpu_query.XXXXXX)"
     trap 'rm -rf "$tmp"' EXIT
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.benchmarks.tpch_join \
       --platform cpu --n-ranks 8 --query q3 --scale-factor 0.01 \
       --iterations 1 --explain --telemetry "$tmp/tel" \
@@ -575,8 +560,8 @@ PY
     # must be oracle-clean on both modes, multiset-equal, zero warm
     # traces, wire-exact — its counter signature is the
     # sortpath_smoke baseline the perfgate lane also gates. Wall
-    # time is never gated on the CPU mesh (emulation, not perf —
-    # the real segmented-vs-flat number rides relay step 10).
+    # time is never gated on the CPU mesh (emulation, not perf;
+    # the segmented-vs-flat chip number is not measured yet).
     set -e
     timeout -k 10 600 env JAX_PLATFORMS=cpu python -m pytest \
       tests/ -q -m sortpath --continue-on-collection-errors \
@@ -584,7 +569,6 @@ PY
     tmp="$(mktemp -d /tmp/djtpu_sortpath.XXXXXX)"
     trap 'rm -rf "$tmp"' EXIT
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.benchmarks.distributed_join \
       --platform cpu --n-ranks 8 \
       --build-table-nrows 8000 --probe-table-nrows 8000 \
@@ -637,7 +621,6 @@ PY
     # telemetry-off no-callback invariant.
     exec timeout -k 10 600 env -u DJTPU_VALIDATE_PLANS \
       JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.analysis.lint
     ;;
   chaos)
@@ -679,7 +662,6 @@ PY
     tmp="$(mktemp -d /tmp/djtpu_service.XXXXXX)"
     trap 'rm -rf "$tmp"' EXIT
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.service.server --smoke \
       --platform cpu --n-ranks 8 \
       --history-dir "$tmp/history" \
@@ -713,7 +695,6 @@ print("history store:", s["n_entries"], "entries,",
     tmp="$(mktemp -d /tmp/djtpu_stageprof.XXXXXX)"
     trap 'rm -rf "$tmp"' EXIT
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.benchmarks.distributed_join \
       --platform cpu --n-ranks 8 \
       --build-table-nrows 8000 --probe-table-nrows 8000 \
@@ -768,7 +749,6 @@ PY
     tmp="$(mktemp -d /tmp/djtpu_hier.XXXXXX)"
     trap 'rm -rf "$tmp"' EXIT
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.benchmarks.distributed_join \
       --platform cpu --n-ranks 8 --slices 2 --shuffle hierarchical \
       --build-table-nrows 8000 --probe-table-nrows 8000 \
@@ -811,7 +791,6 @@ PY
     tmp="$(mktemp -d /tmp/djtpu_fleet.XXXXXX)"
     trap 'rm -rf "$tmp"' EXIT
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.service.fleet --smoke \
       --platform cpu --replica-ranks 2 \
       --history-dir "$tmp/history" \
@@ -825,7 +804,6 @@ PY
     # graded against the pandas oracle, drain+replace and the
     # zero-trace warm replacement gated inside the harness.
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.parallel.chaos \
       --fleet 20 --seed 42 \
       --json-output "$tmp/fleet_soak.json" \
@@ -841,7 +819,6 @@ PY
     # spawns a replica that serves the hot signature WARM (zero new
     # traces) before entering rotation.
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.service.fleet --tenant-smoke \
       --platform cpu --replica-ranks 2 \
       --history-dir "$tmp/tenant_history" \
@@ -855,7 +832,6 @@ PY
     # entries and trend keys stay tenant-namespaced, and the
     # replacement serves the quiet tenant's signature warm.
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.parallel.chaos \
       --tenants 4 --seed 42 \
       --json-output "$tmp/tenant_soak.json" \
@@ -893,7 +869,6 @@ PY
     tmp="$(mktemp -d /tmp/djtpu_fleet_ha.XXXXXX)"
     trap 'rm -rf "$tmp"' EXIT
     timeout -k 10 900 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.service.fleet --ha-smoke \
       --platform cpu --replica-ranks 2 \
       --persist-dir "$tmp/ha" \
@@ -905,7 +880,6 @@ PY
     python -m distributed_join_tpu.telemetry.analyze compare \
       "$tmp/fleet_ha_smoke.json" --baseline fleet_ha_smoke
     timeout -k 10 900 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.parallel.chaos \
       --fleet 10 --fleet-fault resident-kill --seed 42 \
       --json-output "$tmp/fleet_ha_soak.json" \
@@ -942,7 +916,6 @@ PY
     tmp="$(mktemp -d /tmp/djtpu_tracing.XXXXXX)"
     trap 'rm -rf "$tmp"' EXIT
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.service.fleet --tracing-smoke \
       --platform cpu --replica-ranks 2 \
       --persist-dir "$tmp/work" \
@@ -977,7 +950,6 @@ PY
     trap 'rm -rf "$tmp"' EXIT
     for phase in cold warm; do
       timeout -k 10 600 env JAX_PLATFORMS=cpu \
-        JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
         python -m distributed_join_tpu.benchmarks.distributed_join \
         --platform cpu --n-ranks 8 \
         --build-table-nrows 8000 --probe-table-nrows 8000 \
@@ -1004,7 +976,6 @@ PY
     # Service-level zero-trace warm gate: the tuned repeat must be a
     # pure dict-lookup dispatch (no new SPMD programs built at all).
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python - "$tmp" <<'PY'
 import sys
 from distributed_join_tpu.benchmarks import force_cpu_platform
@@ -1073,7 +1044,6 @@ print("analyze tune schema: OK,", doc["n_signatures"], "signature(s)")'
     tmp="$(mktemp -d /tmp/djtpu_resident.XXXXXX)"
     trap 'rm -rf "$tmp"' EXIT
     timeout -k 10 600 env JAX_PLATFORMS=cpu \
-      JAX_COMPILATION_CACHE_DIR=/tmp/djtpu_jax_cache \
       python -m distributed_join_tpu.service.server --smoke \
       --platform cpu --n-ranks 8 \
       --history-dir "$tmp/history" \

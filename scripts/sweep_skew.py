@@ -107,8 +107,7 @@ def on_chip_overhead(report):
             # honestly, but only where it is informative: the explicit
             # fat-caps labels never overflow, and the check costs an
             # extra compile+run of the 10M join (review r4). (jit: an
-            # eager 10M join would run op-by-op over this
-            # environment's relay.)
+            # eager 10M join would run op-by-op.)
             if label in ("naive", "skew_default_caps"):
                 entry[label + "_overflow"] = bool(jax.jit(
                     lambda b, p: step(b, p).overflow)(build, probe))

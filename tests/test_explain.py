@@ -533,7 +533,6 @@ def test_driver_explain_end_to_end(tmp_path):
     record = tmp_path / "record.json"
     env = {"JAX_PLATFORMS": "cpu",
            "XLA_FLAGS": "--xla_force_host_platform_device_count=8",
-           "JAX_COMPILATION_CACHE_DIR": "/tmp/djtpu_jax_cache",
            "PATH": "/usr/bin:/bin"}
     rc = subprocess.run(
         [sys.executable, "-m",

@@ -855,3 +855,24 @@ def test_fleet_module_exports():
     assert callable(fleet_mod.process_fleet_factory)
     assert callable(fleet_mod.run_fleet_smoke)
     assert hasattr(fleet_mod, "main")
+
+
+@pytest.mark.parametrize("platform,cfg_kw,refused", [
+    ("tpu", dict(n_replicas=2), True),
+    ("default", dict(n_replicas=1, autoscale=True,
+                     autoscale_max_replicas=3), True),
+    ("tpu", dict(n_replicas=1), False),
+    ("cpu", dict(n_replicas=4), False),
+])
+def test_process_fleet_refuses_two_processes_per_chip(platform, cfg_kw,
+                                                      refused):
+    """A chip belongs to one process: a process fleet that may hold two
+    replica processes on a chip platform refuses before spawning."""
+    cfg = fleet_mod.FleetConfig(**cfg_kw)
+    if refused:
+        with pytest.raises(fleet_mod.ChipSharingError,
+                           match="one process per chip"):
+            fleet_mod.process_fleet_factory(cfg, platform=platform)
+    else:
+        assert callable(fleet_mod.process_fleet_factory(
+            cfg, platform=platform))
