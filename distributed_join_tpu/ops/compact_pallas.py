@@ -170,6 +170,7 @@ def stream_compact(mask: jax.Array, pos: jax.Array, cols, capacity: int,
             functools.partial(
                 _compact_kernel, block=block, chunk=chunk, ck=ck, w=w
             ),
+            name="compact_mxu",
             grid=(nblocks,),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -220,6 +220,7 @@ def plane_compact_stacked(stacked: jax.Array, mask: jax.Array,
             functools.partial(
                 _compact_kernel, block=block, nplanes=P
             ),
+            name="compact_planes",
             grid=(nblocks,),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -658,6 +658,7 @@ def _expand_gather_b8(S, cols, out_capacity, block, interpret, lo,
                     _expand_kernel_b8, block=block, chunk=chunk,
                     ck8=ck8, ckb8=ckb8, wr=wr, w1w=w1w, w2w=w2w,
                 ),
+                name="expand_b8",
                 grid=(qb,),
                 in_specs=[
                     pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -829,6 +830,7 @@ def expand_gather(S: jax.Array, cols: Sequence[jax.Array],
                     _expand_kernel, block=block, chunk=chunk,
                     ck=ck, srow=srow,
                 ),
+                name="expand",
                 grid=(qb,),
                 in_specs=[
                     pl.BlockSpec(memory_space=pltpu.SMEM),

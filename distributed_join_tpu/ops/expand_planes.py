@@ -259,6 +259,7 @@ def expand_pull(S: jax.Array, cols, out_capacity: int,
             functools.partial(
                 _expand_kernel, block=block, nrec=nrec, nbuild=nbuild
             ),
+            name="expand_planes",
             grid=(nblk,),
             in_specs=(
                 [pl.BlockSpec(memory_space=pltpu.SMEM)] * 4

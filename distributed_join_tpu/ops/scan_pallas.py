@@ -256,6 +256,7 @@ def join_scans(tag: jax.Array, first: jax.Array,
     with jax.enable_x64(False):
         matched2 = pl.pallas_call(
             _scan_r_kernel,
+            name="join_scan_r",
             grid=(nblocks,),
             in_specs=[rspec, rspec],
             out_specs=rspec,
@@ -269,6 +270,7 @@ def join_scans(tag: jax.Array, first: jax.Array,
 
         outs = pl.pallas_call(
             _scan_f_kernel,
+            name="join_scan_f",
             grid=(nblocks,),
             in_specs=[spec, spec, spec],
             out_specs=[spec] * 5,

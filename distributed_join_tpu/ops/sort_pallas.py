@@ -467,6 +467,7 @@ def _merge_level(stacked, a0, b0, p, dirs,
             functools.partial(
                 _merge_tile_kernel, tile=tile, nplanes=P, nkeys=nkeys
             ),
+            name="sort_merge_tile",
             grid=(ntiles,),
             in_specs=in_specs,
             out_specs=out_specs,

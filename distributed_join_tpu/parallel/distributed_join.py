@@ -34,6 +34,7 @@ from __future__ import annotations
 import math
 from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 
 from distributed_join_tpu import telemetry
@@ -340,11 +341,11 @@ def make_join_step(
     (e.g. the retry attempt index) into the same vector. The default
     (``False``) compiles the exact seed program — no aux output, no
     extra collective (tests/test_telemetry.py locks the treedef and
-    program count). Stage spans (`partition`/`shuffle`/`join`) are
-    emitted whenever a telemetry session is active; inside this traced
-    step they time TRACING and carry the pipeline structure into the
-    Chrome trace, while their ``jax.named_scope`` lines the same names
-    up against real device timings in an XLA profile.
+    program count). The stages are ``jax.named_scope``s
+    (`partition`/`shuffle`/`join`, `skew`; the local join nests
+    `prepare`/`sort`/`scan`/`compact`/`expand` under `join`), always
+    on: they name every device op in an XLA profile through its
+    ``op_name`` metadata and change no instruction.
 
     Wire integrity (docs/FAILURE_SEMANTICS.md "Integrity contract"):
     ``with_integrity=True`` additionally computes order-invariant
@@ -569,7 +570,7 @@ def make_join_step(
             from distributed_join_tpu.ops.hashing import hash_columns
             from distributed_join_tpu.parallel import skew
 
-            with telemetry.span("skew"):
+            with jax.named_scope("skew"):
                 # Detect/mark heavy hitters on the uint64 key-tuple
                 # hash: classification only needs to be CONSISTENT
                 # across sides and ranks (hash collisions merely
@@ -643,7 +644,7 @@ def make_join_step(
             # permutations. Skip them entirely (the join handles masked
             # validity natively); this is the reference's 1-rank path,
             # which also partitions into nranks=1 buckets and joins.
-            with telemetry.span("join"):
+            with jax.named_scope("join"):
                 res = sort_merge_inner_join(
                     build_local, probe_local, keys_eff, out_cap,
                     build_payload=bpay, probe_payload=ppay,
@@ -668,7 +669,7 @@ def make_join_step(
                 p_rows, n, k, seg, shuffle_capacity_factor)
             out_cap_s = seg_ops.segmented_out_capacity(
                 p_rows, k, seg, out_capacity_factor, out_rows_per_rank)
-            with telemetry.span("partition"):
+            with jax.named_scope("partition"):
                 ptb = radix_hash_partition(build_local, keys_eff, nb,
                                            sub_buckets=seg)
                 ptp = radix_hash_partition(probe_local, keys_eff, nb,
@@ -694,7 +695,7 @@ def make_join_step(
                         jnp.int64(cap)
                         - jnp.max(pt.counts).astype(jnp.int64))
             for b in range(k):
-                with telemetry.span("shuffle", batch=b):
+                with jax.named_scope("shuffle"):
                     rb_cols, rb_counts, ovf_b = \
                         _batch_shuffle_segmented(
                             comm, ptb, b, n, seg, b_cap_s, shuffle,
@@ -703,7 +704,7 @@ def make_join_step(
                         _batch_shuffle_segmented(
                             comm, ptp, b, n, seg, p_cap_s, shuffle,
                             tape=tp, digest_tape=dtp)
-                with telemetry.span("join", batch=b):
+                with jax.named_scope("join"):
                     bcols, bval = seg_ops.runs_from_blocks(
                         rb_cols, rb_counts)
                     pcols, pval = seg_ops.runs_from_blocks(
@@ -726,7 +727,7 @@ def make_join_step(
                 else []
             vp = _varwidth_cols(probe_local) if shuffle == "ragged" \
                 else []
-            with telemetry.span("partition"):
+            with jax.named_scope("partition"):
                 ptb = radix_hash_partition(
                     build_local, keys_eff, nb,
                     order_within=vb[0] + "#len" if vb else None)
@@ -751,7 +752,7 @@ def make_join_step(
                         jnp.int64(cap)
                         - jnp.max(pt.counts).astype(jnp.int64))
             for b in range(k):
-                with telemetry.span("shuffle", batch=b):
+                with jax.named_scope("shuffle"):
                     recv_build, ovf_b = _batch_shuffle(
                         comm, ptb, b, n, b_cap, mode=shuffle,
                         compression_bits=compression_bits, varwidth=vb,
@@ -762,7 +763,7 @@ def make_join_step(
                         compression_bits=compression_bits, varwidth=vp,
                         tape=tp, digest_tape=dtp,
                         dcn_codec_on=dcn_on)
-                with telemetry.span("join", batch=b):
+                with jax.named_scope("join"):
                     res = sort_merge_inner_join(
                         recv_build, recv_probe, keys_eff, out_cap,
                         build_payload=bpay, probe_payload=ppay,
@@ -878,14 +879,14 @@ def _make_join_agg_step(comm, spec, *, keys, k,
         overflow = jnp.bool_(False)
         parts = []
         if nb == 1:
-            with telemetry.span("join_agg"):
+            with jax.named_scope("join_agg"):
                 partials, t, _g, ovf = agg_ops.local_join_aggregate(
                     build_w, probe_w, keys, spec, mode, groups_cap)
             parts.append(partials)
             total = total + t
             overflow = overflow | ovf
         else:
-            with telemetry.span("partition"):
+            with jax.named_scope("partition"):
                 ptb = radix_hash_partition(build_w, keys, nb)
                 ptp = radix_hash_partition(probe_w, keys, nb)
             tb = tape.scoped("build") if tape is not None else None
@@ -903,7 +904,7 @@ def _make_join_agg_step(comm, spec, *, keys, k,
                         jnp.int64(cap)
                         - jnp.max(pt.counts).astype(jnp.int64))
             for b in range(k):
-                with telemetry.span("shuffle", batch=b):
+                with jax.named_scope("shuffle"):
                     recv_build, ovf_b = _batch_shuffle(
                         comm, ptb, b, n, b_cap, mode=shuffle,
                         compression_bits=compression_bits,
@@ -912,7 +913,7 @@ def _make_join_agg_step(comm, spec, *, keys, k,
                         comm, ptp, b, n, p_cap, mode=shuffle,
                         compression_bits=compression_bits,
                         tape=tp, digest_tape=dtp, dcn_codec_on=dcn_on)
-                with telemetry.span("join_agg", batch=b):
+                with jax.named_scope("join_agg"):
                     partials, t, _g, ovf_j = \
                         agg_ops.local_join_aggregate(
                             recv_build, recv_probe, keys, spec, mode,
@@ -930,7 +931,7 @@ def _make_join_agg_step(comm, spec, *, keys, k,
             if len(parts) > 1:
                 # Non-key groups recur across batches — one combine
                 # (concat + regroup sort at groups size) settles them.
-                with telemetry.span("agg_combine"):
+                with jax.named_scope("agg_combine"):
                     combined, _g, ovf_c = agg_ops.combine_partials(
                         parts, spec, group_names, lanes_schema,
                         groups_cap)
@@ -944,7 +945,7 @@ def _make_join_agg_step(comm, spec, *, keys, k,
                 # valid partials); the post-exchange combine's flag
                 # fires if one rank receives more distinct groups than
                 # its block holds.
-                with telemetry.span("partials_exchange"):
+                with jax.named_scope("partials_exchange"):
                     ptg = radix_hash_partition(parts[0], group_names,
                                                n)
                     tg = tape.scoped("partials") if tape is not None \
@@ -1174,7 +1175,7 @@ def make_probe_join_step(
         total = jnp.int64(0)
         overflow = jnp.bool_(False)
         if nb == 1:
-            with telemetry.span("join"):
+            with jax.named_scope("join"):
                 res = sort_merge_inner_join(
                     resident_local, probe_local, keys, out_cap,
                     build_payload=build_payload,
@@ -1185,7 +1186,7 @@ def make_probe_join_step(
             total = total + res.total.astype(jnp.int64)
             overflow = overflow | res.overflow
         else:
-            with telemetry.span("partition"):
+            with jax.named_scope("partition"):
                 ptp = radix_hash_partition(probe_local, keys, nb)
             tp = tape.scoped("probe") if tape is not None else None
             dtp = tape.scoped("probe.integrity") if with_integrity \
@@ -1198,12 +1199,12 @@ def make_probe_join_step(
                     jnp.int64(p_cap)
                     - jnp.max(ptp.counts).astype(jnp.int64))
             for b in range(k):
-                with telemetry.span("shuffle", batch=b):
+                with jax.named_scope("shuffle"):
                     recv_probe, ovf_p = _batch_shuffle(
                         comm, ptp, b, n, p_cap, mode=shuffle,
                         compression_bits=compression_bits, tape=tp,
                         digest_tape=dtp)
-                with telemetry.span("join", batch=b):
+                with jax.named_scope("join"):
                     res = sort_merge_inner_join(
                         resident_local, recv_probe, keys, out_cap,
                         build_payload=build_payload,
@@ -1303,14 +1304,14 @@ def _make_probe_agg_step(comm, spec, *, keys, k,
         overflow = jnp.bool_(False)
         parts = []
         if nb == 1:
-            with telemetry.span("join_agg"):
+            with jax.named_scope("join_agg"):
                 partials, t, _g, ovf = agg_ops.local_join_aggregate(
                     resident_w, probe_w, keys, spec, mode, groups_cap)
             parts.append(partials)
             total = total + t
             overflow = overflow | ovf
         else:
-            with telemetry.span("partition"):
+            with jax.named_scope("partition"):
                 ptp = radix_hash_partition(probe_w, keys, nb)
             tp = tape.scoped("probe") if tape is not None else None
             dtp = tape.scoped("probe.integrity") if with_integrity \
@@ -1323,12 +1324,12 @@ def _make_probe_agg_step(comm, spec, *, keys, k,
                     jnp.int64(p_cap)
                     - jnp.max(ptp.counts).astype(jnp.int64))
             for b in range(k):
-                with telemetry.span("shuffle", batch=b):
+                with jax.named_scope("shuffle"):
                     recv_probe, ovf_p = _batch_shuffle(
                         comm, ptp, b, n, p_cap, mode=shuffle,
                         compression_bits=compression_bits, tape=tp,
                         digest_tape=dtp)
-                with telemetry.span("join_agg", batch=b):
+                with jax.named_scope("join_agg"):
                     partials, t, _g, ovf_j = \
                         agg_ops.local_join_aggregate(
                             resident_w, recv_probe, keys, spec, mode,
@@ -1338,14 +1339,14 @@ def _make_probe_agg_step(comm, spec, *, keys, k,
                 overflow = overflow | ovf_p | ovf_j
         if mode == "probe":
             if len(parts) > 1:
-                with telemetry.span("agg_combine"):
+                with jax.named_scope("agg_combine"):
                     combined, _g, ovf_c = agg_ops.combine_partials(
                         parts, spec, group_names, lanes_schema,
                         groups_cap)
                 overflow = overflow | ovf_c
                 parts = [combined]
             if n > 1:
-                with telemetry.span("partials_exchange"):
+                with jax.named_scope("partials_exchange"):
                     ptg = radix_hash_partition(parts[0], group_names,
                                                n)
                     tg = tape.scoped("partials") if tape is not None \
@@ -1568,25 +1569,31 @@ def distributed_inner_join(
 
     n = comm.n_ranks
 
-    tuned = None
-    if tuner is not None:
-        # Resolved on the UNPADDED tables and pre-tuned opts — the
-        # same basis JoinService keys its history entries on, so the
-        # signature the tuner reads is the one the store wrote.
-        tuned = tuner.resolve(comm, build, probe, key=key,
-                              with_integrity=verify_integrity,
-                              opts=opts)
-        opts = tuned.apply(opts)
+    # Host spans (telemetry.span: a TraceAnnotation, plus a sink event
+    # with a session) split each call on the profiler's clock:
+    # prepare -> resolve (-> compile on a program-cache miss) ->
+    # dispatch -> fetch_overflow -> finish.
+    with telemetry.span("entry.prepare"):
+        tuned = None
+        if tuner is not None:
+            # Resolved on the UNPADDED tables and pre-tuned opts — the
+            # same basis JoinService keys its history entries on, so
+            # the signature the tuner reads is the one the store wrote.
+            tuned = tuner.resolve(comm, build, probe, key=key,
+                                  with_integrity=verify_integrity,
+                                  opts=opts)
+            opts = tuned.apply(opts)
 
-    build = build.pad_to(_round_up(build.capacity, n))
-    probe = probe.pad_to(_round_up(probe.capacity, n))
-    if hasattr(comm, "device_put_sharded"):
-        build, probe = comm.device_put_sharded((build, probe))
+        build = build.pad_to(_round_up(build.capacity, n))
+        probe = probe.pad_to(_round_up(probe.capacity, n))
+        if hasattr(comm, "device_put_sharded"):
+            build, probe = comm.device_put_sharded((build, probe))
 
-    ladder = resolve_join_ladder(build, probe, n, opts,
-                                 n_slices=getattr(comm, "n_slices", 1))
-    if tuned is not None:
-        ladder.seed_rung(tuned.rung)
+    with telemetry.span("entry.resolve"):
+        ladder = resolve_join_ladder(
+            build, probe, n, opts, n_slices=getattr(comm, "n_slices", 1))
+        if tuned is not None:
+            ladder.seed_rung(tuned.rung)
     last_sig = None
     for attempt in range(auto_retry + 1):
         # The rung label is ABSOLUTE (ladder.base_rung + attempt): a
@@ -1594,82 +1601,88 @@ def distributed_inner_join(
         # the same program signature — as the executable the cold
         # run's escalation already traced at this sizing.
         rung = ladder.base_rung + attempt
-        if program_cache is not None:
-            fn, _ = program_cache.get(
-                build, probe, key=key,
-                with_integrity=verify_integrity,
-                metrics_static={"retry_attempt_max": rung},
-                **ladder.sizing(), **opts)
-            last_sig = fn.signature
-        else:
-            fn = make_distributed_join(comm, key=key,
-                                       with_integrity=verify_integrity,
-                                       metrics_static={
-                                           "retry_attempt_max": rung},
-                                       **ladder.sizing(), **opts)
+        with telemetry.span("entry.resolve"):
+            if program_cache is not None:
+                fn, _ = program_cache.get(
+                    build, probe, key=key,
+                    with_integrity=verify_integrity,
+                    metrics_static={"retry_attempt_max": rung},
+                    **ladder.sizing(), **opts)
+                last_sig = fn.signature
+            else:
+                fn = make_distributed_join(
+                    comm, key=key, with_integrity=verify_integrity,
+                    metrics_static={"retry_attempt_max": rung},
+                    **ladder.sizing(), **opts)
         if faults.plan_validation_enabled():
             # The violation record is process-global; drop leftovers
             # from earlier unchecked programs so what check() raises
             # below belongs to THIS attempt.
             faults.clear_plan_violations()
-        res = fn(build, probe)
-        overflow = bool(res.overflow)
-        if faults.plan_validation_enabled():
-            # The flag fetch above sequenced the validation callbacks;
-            # surface a recorded inconsistency as the loud error it is
-            # rather than retrying a corrupted-metadata exchange.
-            faults.check_plan_violations()
-        report = None
-        if verify_integrity and not overflow:
-            # Overflow attempts skip verification: a clamp drops rows
-            # by design and the overflow rung already forces a retry.
-            report = integrity.verify_join_result(res)
-        ladder.note(overflow,
-                    integrity_ok=None if report is None else report.ok)
-        failed = overflow or (report is not None and not report.ok)
-        if attempt == auto_retry or not failed:
-            # retry_report is host-side metadata, not a pytree field:
-            # JoinResult traces through shard_map, and the report only
-            # exists outside the compiled program.
-            object.__setattr__(res, "retry_report", ladder.report())
-            if tuned is not None:
-                object.__setattr__(res, "tuned", tuned.as_record())
-            if explain:
-                # Host arithmetic only (no trace/compile): the plan of
-                # the attempt that produced THIS result — its digest is
-                # the program cache's key for the same sizing.
-                from distributed_join_tpu import planning
+        with telemetry.span("entry.dispatch"):
+            res = fn(build, probe)
+        # out_rows is the program's own output sizing, read from the
+        # result's static shape: nothing is fetched for it.
+        with telemetry.span("entry.fetch_overflow",
+                            out_rows=res.table.capacity, rung=rung):
+            overflow = bool(res.overflow)
+        with telemetry.span("entry.finish"):
+            if faults.plan_validation_enabled():
+                # The flag fetch above sequenced the validation callbacks;
+                # surface a recorded inconsistency as the loud error it is
+                # rather than retrying a corrupted-metadata exchange.
+                faults.check_plan_violations()
+            report = None
+            if verify_integrity and not overflow:
+                # Overflow attempts skip verification: a clamp drops rows
+                # by design and the overflow rung already forces a retry.
+                report = integrity.verify_join_result(res)
+            ladder.note(overflow,
+                        integrity_ok=None if report is None else report.ok)
+            failed = overflow or (report is not None and not report.ok)
+            if attempt == auto_retry or not failed:
+                # retry_report is host-side metadata, not a pytree field:
+                # JoinResult traces through shard_map, and the report only
+                # exists outside the compiled program.
+                object.__setattr__(res, "retry_report", ladder.report())
+                if tuned is not None:
+                    object.__setattr__(res, "tuned", tuned.as_record())
+                if explain:
+                    # Host arithmetic only (no trace/compile): the plan of
+                    # the attempt that produced THIS result — its digest is
+                    # the program cache's key for the same sizing.
+                    from distributed_join_tpu import planning
 
-                object.__setattr__(res, "plan", planning.build_plan(
-                    comm, build, probe, key=key,
-                    with_integrity=verify_integrity,
-                    metrics_static={"retry_attempt_max": rung},
-                    **ladder.sizing(), **opts))
-            if report is not None:
-                object.__setattr__(res, "integrity_report", report)
-            # Fold the device metrics of the FINAL attempt into the
-            # telemetry session (one host fetch, after the retry loop
-            # settled — the flag fetch above already synced).
-            telemetry.emit_metrics(getattr(res, "telemetry", None))
-            if report is not None and not report.ok:
-                # Never hand corrupt rows back as a result. The
-                # budget-exhausted rung is as tainted as a retried
-                # one: a resident (or persisted) program that failed
-                # verification must not serve the next same-signature
-                # request.
+                    object.__setattr__(res, "plan", planning.build_plan(
+                        comm, build, probe, key=key,
+                        with_integrity=verify_integrity,
+                        metrics_static={"retry_attempt_max": rung},
+                        **ladder.sizing(), **opts))
+                if report is not None:
+                    object.__setattr__(res, "integrity_report", report)
+                # Fold the device metrics of the FINAL attempt into the
+                # telemetry session (one host fetch, after the retry loop
+                # settled — the flag fetch above already synced).
+                telemetry.emit_metrics(getattr(res, "telemetry", None))
+                if report is not None and not report.ok:
+                    # Never hand corrupt rows back as a result. The
+                    # budget-exhausted rung is as tainted as a retried
+                    # one: a resident (or persisted) program that failed
+                    # verification must not serve the next same-signature
+                    # request.
+                    if program_cache is not None and last_sig is not None:
+                        program_cache.evict(last_sig)
+                    raise integrity.IntegrityError(report)
+                return res
+            if overflow:
+                ladder.escalate()
+            else:
+                # Integrity mismatch: rerun the SAME sizing — the rows
+                # were wrong, not too many. Every retry recompiles (a
+                # cached entry for this rung is evicted first), so a
+                # deterministic injected corruption budget (FaultPlan)
+                # exhausts and the rerun can verify clean.
                 if program_cache is not None and last_sig is not None:
                     program_cache.evict(last_sig)
-                raise integrity.IntegrityError(report)
-            return res
-        if overflow:
-            ladder.escalate()
-        else:
-            # Integrity mismatch: rerun the SAME sizing — the rows
-            # were wrong, not too many. Every retry recompiles (a
-            # cached entry for this rung is evicted first), so a
-            # deterministic injected corruption budget (FaultPlan)
-            # exhausts and the rerun can verify clean.
-            if program_cache is not None and last_sig is not None:
-                program_cache.evict(last_sig)
-            ladder.hold("retry_integrity")
+                ladder.hold("retry_integrity")
     raise AssertionError("unreachable")

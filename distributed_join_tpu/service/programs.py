@@ -285,10 +285,13 @@ class JoinProgramCache:
             self._entries.move_to_end(sig)
             return entry, True
         self.misses += 1
-        entry = self._load_persisted(sig)
-        if entry is None:
-            entry = self._build(sig, build, probe,
-                                dict(opts, with_metrics=with_metrics))
+        # The AOT and disk tiers compile or load here; a trace-tier
+        # program traces and compiles at its first dispatch instead.
+        with telemetry.span("entry.compile", digest=sig.digest()[:12]):
+            entry = self._load_persisted(sig)
+            if entry is None:
+                entry = self._build(sig, build, probe,
+                                    dict(opts, with_metrics=with_metrics))
         self._admit_entry(sig, entry)
         return entry, False
 

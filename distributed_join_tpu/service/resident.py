@@ -55,6 +55,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -237,16 +238,16 @@ def make_resident_prep_step(comm, key="key",
             b_cap = _round_up(
                 int(math.ceil(b_rows / n * shuffle_capacity_factor)),
                 8)
-            with telemetry.span("partition"):
+            with jax.named_scope("partition"):
                 pt = radix_hash_partition(build_local, keys, n)
-            with telemetry.span("shuffle"):
+            with jax.named_scope("shuffle"):
                 recv, ovf = _batch_shuffle(comm, pt, 0, n, b_cap,
                                            mode=shuffle)
         if recv.capacity > resident_rows_per_rank:
             raise ValueError(
                 f"resident capacity {resident_rows_per_rank} below "
                 f"the shuffle receive block {recv.capacity}")
-        with telemetry.span("sort"):
+        with jax.named_scope("sort"):
             run = _key_sorted_prefix(
                 recv.pad_to(resident_rows_per_rank), keys)
         rows, digest = _run_accounting(comm, run, keys)
@@ -276,7 +277,7 @@ def make_run_merge_step(comm, key="key"):
             },
             jnp.concatenate([base_local.valid, run_local.valid]),
         )
-        with telemetry.span("merge_sort"):
+        with jax.named_scope("merge_sort"):
             sorted_ = _key_sorted_prefix(merged, keys)
         valid_total = jnp.sum(sorted_.valid.astype(jnp.int32))
         ovf = valid_total > base_cap

@@ -13,8 +13,9 @@ session with three parts (docs/OBSERVABILITY.md is the contract):
 - :mod:`.spans` — hierarchical host-side span timer with honest sync
   semantics (fetch ONE scalar at span close, per ``benchmarking.py``'s
   protocol; never bare ``block_until_ready``), emitting both a sink
-  event and a ``jax.profiler.TraceAnnotation``/``jax.named_scope`` so
-  spans line up inside XLA device profiles;
+  event and a ``jax.profiler.TraceAnnotation`` so host spans line up
+  with the device planes of an XLA profile. Stages inside the compiled
+  step are ``jax.named_scope``s, not spans: always on, metadata only;
 - :mod:`.metrics` — device-side counters that travel as an auxiliary
   ``Metrics`` pytree OUTPUT of the compiled SPMD join step (no host
   callbacks inside jit), cross-rank aggregated with one
@@ -30,13 +31,15 @@ efficiency, retry cost, with knob recommendations; every driver's
 signatures + the ``compare`` perf gate of ``run_tier1.sh perfgate``).
 Both are device-free: they consume the files, never the session.
 
-The hard contract: **telemetry OFF is the exact seed hot path** — no
-extra aux outputs, no recompilation, zero overhead. Every function in
-this module is a no-op (and :func:`span` a shared nullcontext) until
-:func:`configure` activates a session; ``make_join_step`` only emits
-the aux ``Metrics`` output when explicitly asked
-(``with_metrics=True``) or when a session is active at build time via
-``make_distributed_join``'s ``with_metrics=None`` resolution.
+The hard contract: **telemetry OFF is the seed hot path** — no extra
+aux outputs, no recompilation, no sink writes. Every function in this
+module is a no-op until :func:`configure` activates a session, except
+:func:`span`, which still enters a ``TraceAnnotation`` (one activity
+check while no profiler runs); ``make_join_step`` only emits the aux
+``Metrics`` output when explicitly asked (``with_metrics=True``) or
+when a session is active at build time via ``make_distributed_join``'s
+``with_metrics=None`` resolution. The step's stage scopes are on
+either way: they change instruction metadata, never instructions.
 Tested by ``tests/test_telemetry.py`` (treedef/program-count parity
 with the seed plus counter-vs-pandas-oracle checks).
 """
@@ -45,6 +48,8 @@ from __future__ import annotations
 
 import contextlib
 from typing import Optional
+
+import jax
 
 from distributed_join_tpu.telemetry.export import TelemetrySink
 from distributed_join_tpu.telemetry.metrics import Metrics, MetricsTape
@@ -59,13 +64,12 @@ __all__ = [
 ]
 
 _active: Optional[TelemetrySink] = None
-_null = contextlib.nullcontext()
 
 
 def enabled() -> bool:
-    """Whether a telemetry session is active. Gates EVERYTHING: with no
-    session, spans are a shared nullcontext, events/counters no-ops,
-    and the join step compiles the exact seed program."""
+    """Whether a telemetry session is active. With no session nothing
+    reaches the sink, events/counters are no-ops, spans reach only a
+    running profiler, and the join step compiles the seed program."""
     return _active is not None
 
 
@@ -163,14 +167,38 @@ def session(out_dir: str, *, trace: bool = False, rank: Optional[int] = None):
             finalize()
 
 
+class _ProfilerSpan:
+    """:func:`span` with no session: a ``TraceAnnotation`` alone, so
+    the span still lands in any running profiler trace (payload as its
+    arguments) and costs one activity check when none runs. Yields
+    None, like an inactive span always has."""
+
+    __slots__ = ("_annotation",)
+
+    def __init__(self, name: str, payload: dict):
+        self._annotation = jax.profiler.TraceAnnotation(name, **payload)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        return None
+
+    def __exit__(self, *exc):
+        return self._annotation.__exit__(*exc)
+
+
 def span(name: str, **payload):
-    """Hierarchical span context manager (no-op nullcontext when
-    telemetry is off). The yielded handle supports ``note(**kv)`` and
-    ``sync_on(scalar)`` — the scalar is fetched (ONE value to host) at
-    span close so the span honestly covers device completion; see
-    :mod:`.spans` for the sync-semantics contract."""
+    """Hierarchical host span context manager. With a session active
+    it records a sink event and yields a handle supporting
+    ``note(**kv)`` and ``sync_on(scalar)`` — the scalar is fetched
+    (ONE value to host) at span close so the span honestly covers
+    device completion; see :mod:`.spans` for the sync-semantics
+    contract. Either way the span enters a
+    ``jax.profiler.TraceAnnotation`` carrying ``payload`` as its
+    arguments; without a session that is all it does, and it yields
+    None. Host code only: a stage inside traced code is a
+    ``jax.named_scope``."""
     if _active is None:
-        return _null
+        return _ProfilerSpan(name, payload)
     return _spans.span_scope(_active, name, payload or None)
 
 

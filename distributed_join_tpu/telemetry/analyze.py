@@ -13,7 +13,7 @@ the loop:
   across ranks), key-skew Gini over the per-rank row counters,
   overflow-margin headroom, wire-byte efficiency (actual vs. ideal
   payload incl. varwidth prefixes and compression savings), retry-
-  ladder cost, and the host-side stage split;
+  ladder cost, and the host span totals;
 - :func:`recommend` maps warning indicators to the CONCRETE knobs
   that relieve them (``--skew-threshold``/``--hh-*`` in
   ``parallel/skew.py``'s PRPD path, ``--shuffle-capacity-factor`` /
@@ -406,10 +406,10 @@ def _retry_ladder(run: RunData) -> dict:
 
 
 def _stage_split(run: RunData) -> dict:
-    """Host-visible span totals (from the rank-0 summary): where the
-    run's wall time went. Spans inside the compiled step time tracing,
-    not execution (docs/OBSERVABILITY.md) — this is the HOST split;
-    the device split needs ``--trace``'s XLA profile."""
+    """Host span totals (from the rank-0 summary): where the run's
+    host wall time went. Spans are host code only; the stages inside
+    the compiled step are device work, split by their scopes in
+    ``--trace``'s XLA profile (docs/OBSERVABILITY.md)."""
     if not run.summary or not run.summary.get("spans"):
         return {"status": "unknown"}
     spans = {path: {"count": st.get("count"),
@@ -635,7 +635,7 @@ def format_report(diag: dict) -> str:
                      f"sizing {retry.get('final_sizing')}")
     split = ind["stage_split"]
     if split.get("spans"):
-        lines.append("  host stage split (s):")
+        lines.append("  host spans (s):")
         for path, st in split["spans"].items():
             lines.append(f"    {path:<28} {st['total_s']:>10.4f} "
                          f"x{st['count']}")

@@ -10,15 +10,18 @@ numbers honest in this environment (the same protocol as
    orders the host clock after the device work on every backend. A span that should cover device completion registers that
    scalar via ``sp.sync_on(scalar)`` and the fetch happens at span
    close, inside the measured interval.
-2. **Spans inside traced code time TRACING, not execution.** The whole
-   partition->shuffle->join pipeline is ONE compiled program; a host
-   timer around a stage inside ``jit`` measures trace time. Such spans
-   are still emitted (they carry the pipeline STRUCTURE into the
-   Chrome trace, and tracing cost is itself a real number), and each
-   span also enters a ``jax.named_scope`` + ``jax.profiler.
-   TraceAnnotation`` so the same names line up against real device
-   timings inside an XLA profile (``--trace``). Device-side *values*
-   travel via :mod:`.metrics`, never host callbacks.
+2. **Spans are host code; stages inside traced code are scopes.**
+   The whole partition->shuffle->join pipeline is ONE compiled
+   program, and a host timer around a stage inside ``jit`` would time
+   tracing. So the step names its stages with ``jax.named_scope``
+   (always on: the names ride the instructions' ``op_name`` metadata
+   into every XLA profile and change no instruction), and a span is
+   only ever entered by code that runs on the host. Each span enters
+   a ``jax.profiler.TraceAnnotation`` (its payload as arguments), so
+   it lands in a running profiler's trace on the device planes'
+   clock; with a session active it is also recorded in the sink.
+   Device-side *values* travel via :mod:`.metrics`, never host
+   callbacks.
 
 Span nesting is tracked per thread (the out-of-core staging/fetch
 workers each get their own stack); the sink records the full
@@ -84,7 +87,7 @@ class Span:
 @contextmanager
 def span_scope(sink, name: str, payload: Optional[dict] = None):
     """The active-session span implementation behind
-    ``telemetry.span`` (which returns a nullcontext when off)."""
+    ``telemetry.span`` (a bare ``TraceAnnotation`` when off)."""
     import jax
 
     stack = _stack()
@@ -93,7 +96,7 @@ def span_scope(sink, name: str, payload: Optional[dict] = None):
     stack.append(sp)
     err = None
     try:
-        with jax.named_scope(name), jax.profiler.TraceAnnotation(name):
+        with jax.profiler.TraceAnnotation(name, **sp.payload):
             sp.t0 = time.perf_counter()
             try:
                 yield sp

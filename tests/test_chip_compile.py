@@ -6,12 +6,17 @@ limits, tiling, unaligned slices. These tests compile each kernel with
 config 1) hands it — a 20M-row merged domain and the default 1.2x output
 capacity of 12M slots — and the four-chip ragged exchange at config
 2's 12.5M rows per rank, for chips that are described, not attached
-(on-chip-measurement guide, section 2). Nothing runs.
+(an ahead-of-time compile against a described TPU topology). The whole
+one-chip join step,
+compiled at a small size, shows the stage scopes and kernel names that
+an XLA profile of the chip reads. Nothing runs.
 
 The topology is described inside a fixture (never at import): only one
 process may load the TPU library, and the test runner's workers all
 import this file.
 """
+
+import re
 
 import jax
 import jax.numpy as jnp
@@ -137,3 +142,82 @@ def test_ragged_exchange_of_64bit_keys(topo, monkeypatch):
     assert "ragged-all-to-all" in compiled.as_text()
     column = RECV * 8
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * column
+
+
+# -- the one-chip join step: stage scopes and kernel names -------------
+
+JOIN_ROWS = 1024
+STAGES = ("prepare", "sort", "scan", "compact", "expand")
+KERNELS = {"join_scan_r", "join_scan_f", "compact_planes", "expand",
+           "expand_b8"}
+INSTRUCTION = re.compile(r"\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][a-z0-9\-]*)\(")
+
+
+def _join_step_hlo(topo, monkeypatch, scopes=True):
+    """The optimised HLO of the one-chip join step (the Pallas kernel
+    pipeline, as on the chip); ``scopes=False`` traces it with every
+    ``jax.named_scope`` turned into a no-op."""
+    import contextlib
+
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from distributed_join_tpu import device
+    from distributed_join_tpu.parallel.communicator import TpuCommunicator
+    from distributed_join_tpu.parallel.distributed_join import (
+        make_distributed_join,
+    )
+    from distributed_join_tpu.service.programs import abstract_join_tables
+
+    monkeypatch.setattr(device, "on_tpu", lambda: True)
+    if not scopes:
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+    comm = TpuCommunicator(mesh=Mesh(np.array(topo.devices[:1]),
+                                     ("ranks",)))
+    build, probe = abstract_join_tables(comm, JOIN_ROWS)
+    fn = make_distributed_join(comm, key="key")
+    return fn.lower(build, probe).compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def join_hlo(topo):
+    with pytest.MonkeyPatch.context() as mp:
+        return _join_step_hlo(topo, mp)
+
+
+def _instructions(text):
+    """``[(name, shape, opcode, line)]`` of every HLO instruction."""
+    return [(*m.groups(), line) for line in text.splitlines()
+            if (m := INSTRUCTION.match(line))]
+
+
+def test_join_step_names_its_stages_and_kernels(join_hlo):
+    """Every sort, Pallas kernel and concatenate of the local join
+    carries a ``join/<stage>`` scope in its op_name, and each kernel
+    instruction is named after its kernel."""
+    kernels = set()
+    seen = set()
+    for name, _, opcode, line in _instructions(join_hlo):
+        kernel = 'custom_call_target="tpu_custom_call"' in line
+        if not (opcode in ("sort", "concatenate") or kernel):
+            continue
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        assert op_name, line[:200]
+        stages = [s for s in STAGES if f"/join/{s}/" in op_name.group(1)]
+        assert stages, op_name.group(1)
+        seen.update(stages)
+        if kernel:
+            kernels.add(re.sub(r"\.\d+$", "", name))
+    assert kernels == KERNELS
+    assert {"sort", "scan", "compact", "expand"} <= seen
+
+
+def test_scopes_leave_the_instructions_alone(topo, join_hlo, monkeypatch):
+    """Scopes are metadata: the optimised program has the same
+    instructions (names, shapes, opcodes, order) with and without
+    them."""
+    plain = _join_step_hlo(topo, monkeypatch, scopes=False)
+    with_scopes = [i[:3] for i in _instructions(join_hlo)]
+    assert with_scopes == [i[:3] for i in _instructions(plain)]
+    assert len(with_scopes) > 1000

@@ -10,12 +10,18 @@ Two contracts (docs/OBSERVABILITY.md):
   that ride the compiled step as an aux ``Metrics`` pytree match
   pandas-oracle ground truth (rows shuffled, wire bytes, match
   count), span events land in the JSONL log, and the Chrome trace is
-  Perfetto-loadable JSON carrying the partition/shuffle/join stage
-  spans.
+  Perfetto-loadable JSON carrying the run's host spans.
+
+Stages inside the compiled step are ``jax.named_scope``s, on in both
+modes: the partition/shuffle/join names ride the instructions'
+``op_name`` metadata, and the entry's host spans reach a profiler trace
+whether or not a session is active.
 """
 
+import glob
 import json
 import math
+import re
 
 import pytest
 
@@ -72,6 +78,16 @@ def _oracle(build, probe) -> int:
     return len(build.to_pandas().merge(probe.to_pandas(), on="key"))
 
 
+def _layer_scopes(hlo_text: str) -> set:
+    """The layer scopes (partition, shuffle, join) that instructions'
+    op_names carry: every component but the last, which is the
+    primitive."""
+    return {scope
+            for op_name in re.findall(r'op_name="([^"]*)"', hlo_text)
+            for scope in op_name.split("/")[:-1]
+            if scope in ("partition", "shuffle", "join")}
+
+
 # -- telemetry OFF: the seed hot path, bit for bit --------------------
 
 
@@ -121,6 +137,95 @@ def test_explicit_with_metrics_false_wins_over_session(tmp_path):
                                    out_capacity_factor=4.0)
         res = fn(b, p)
         assert not hasattr(res, "telemetry")
+
+
+def test_stage_scopes_on_four_devices():
+    """No session: the four-rank step's instructions carry the
+    partition, shuffle and join scopes all the same."""
+    assert not telemetry.enabled()
+    b, p = _tables()
+    fn = make_distributed_join(TpuCommunicator(n_ranks=4), key="key",
+                               out_capacity_factor=4.0)
+    assert _layer_scopes(fn.lower(b, p).compile().as_text()) == {
+        "partition", "shuffle", "join"}
+
+
+ENTRY_SPANS = ("entry.prepare", "entry.resolve", "entry.dispatch",
+               "entry.fetch_overflow", "entry.finish")
+
+
+def _host_spans(trace_dir: str):
+    """``[(name, start_ns, end_ns, stats)]`` of the host threads'
+    events in the profiler trace under ``trace_dir``."""
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+             dict(e.stats))
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events]
+
+
+@pytest.mark.filterwarnings("ignore:builtin type event_stats")
+def test_entry_spans_reach_a_profiler_trace(tmp_path):
+    """No session: a profiler trace of two calls holds the entry's five
+    host spans inside the caller's annotation, one compile span (the
+    program cache's miss) with the signature digest, and the program's
+    own output sizing as ``out_rows``."""
+    from distributed_join_tpu.service.programs import JoinProgramCache
+
+    assert not telemetry.enabled()
+    b, p = _tables()
+    comm = TpuCommunicator(n_ranks=4)
+    cache = JoinProgramCache(comm)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for _ in range(2):
+            with jax.profiler.TraceAnnotation("caller"):
+                res = dj.distributed_inner_join(
+                    b, p, comm, program_cache=cache,
+                    out_capacity_factor=4.0)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(str(tmp_path))
+    callers = [s for s in spans if s[0] == "caller"]
+    assert len(callers) == 2
+    for name in ENTRY_SPANS + ("entry.compile",):
+        found = [s for s in spans if s[0] == name]
+        assert found, name
+        assert all(any(c[1] <= s[1] and s[2] <= c[2] for c in callers)
+                   for s in found), name
+    fetches = [s[3] for s in spans if s[0] == "entry.fetch_overflow"]
+    assert [{k: st[k] for k in ("out_rows", "rung")} for st in fetches] \
+        == [{"out_rows": res.table.capacity, "rung": 0}] * 2
+    (compile_span,) = [s for s in spans if s[0] == "entry.compile"]
+    assert len(compile_span[3]["digest"]) == 12
+    assert int(res.total) == _oracle(b, p)
+
+
+def test_span_without_session_fetches_nothing(monkeypatch):
+    """No session: a span yields no handle and fetches no scalar, and
+    the entry's spans build no program beyond the one join."""
+    from distributed_join_tpu.service.programs import JoinProgramCache
+    from distributed_join_tpu.telemetry import spans
+
+    assert not telemetry.enabled()
+
+    def no_fetch(x):
+        raise AssertionError("a span fetched a scalar")
+
+    monkeypatch.setattr(spans, "fetch_one_scalar", no_fetch)
+    with telemetry.span("host.work", rows=3) as sp:
+        assert sp is None
+    b, p = _tables()
+    comm = CountingComm(n_ranks=4)
+    cache = JoinProgramCache(comm)
+    for _ in range(2):
+        res = dj.distributed_inner_join(b, p, comm, program_cache=cache,
+                                        out_capacity_factor=4.0)
+    assert comm.programs_built == 1 and cache.traces == 1
+    assert int(res.total) == _oracle(b, p)
 
 
 # -- telemetry ON: counters vs. pandas-oracle ground truth ------------
@@ -242,8 +347,9 @@ def test_out_of_core_phase_counters_and_events(tmp_path):
 def test_join_driver_telemetry_acceptance(tmp_path):
     """ISSUE 2 acceptance: one --telemetry join-driver run on the CPU
     mesh produces a JSONL event log, a Perfetto-loadable Chrome trace
-    with partition/shuffle/join spans, and a JSON record whose
-    embedded counters match the pandas oracle."""
+    of host spans, a program whose instructions carry the
+    partition/shuffle/join scopes, and a JSON record whose embedded
+    counters match the pandas oracle."""
     from distributed_join_tpu.benchmarks import (
         distributed_join as dj_driver,
     )
@@ -261,10 +367,11 @@ def test_join_driver_telemetry_acceptance(tmp_path):
     finally:
         telemetry.finalize()
 
-    want = _oracle(*generate_build_probe_tables(
+    tables = generate_build_probe_tables(
         seed=42, build_nrows=8000, probe_nrows=8000, selectivity=0.3,
         unique_build_keys=True,
-    ))
+    )
+    want = _oracle(*tables)
     assert record["schema_version"] == 2
     assert record["rank"] == 0
     assert record["matches_per_join"] == want
@@ -282,11 +389,17 @@ def test_join_driver_telemetry_acceptance(tmp_path):
     assert any(e["name"] == "metrics" for e in events)
     assert any(e["kind"] == "span" for e in events)
 
-    # Chrome trace: Perfetto-loadable shape with the stage spans.
+    # Chrome trace: Perfetto-loadable shape with the host spans; the
+    # stages are scopes of the compiled step, not trace-time spans.
     trace = json.load(open(tel["trace_path"]))
     assert isinstance(trace["traceEvents"], list)
     names = {e["name"] for e in trace["traceEvents"]}
-    assert {"partition", "shuffle", "join"} <= names
+    assert "collect_metrics" in names
+    assert not {"partition", "shuffle", "join"} & names
+    step = make_distributed_join(TpuCommunicator(n_ranks=8), key="key",
+                                 shuffle="ragged", out_capacity_factor=3.0)
+    assert {"partition", "shuffle", "join"} <= _layer_scopes(
+        step.lower(*tables).compile().as_text())
     complete = [e for e in trace["traceEvents"] if e["ph"] == "X"]
     assert complete and all(
         {"name", "ts", "dur", "pid", "tid"} <= set(e) for e in complete
