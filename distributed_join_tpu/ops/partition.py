@@ -4,16 +4,27 @@ The reference's partition step (SURVEY.md §2 "Hash partition step") is a
 Murmur3 radix scatter on GPU. Scatters are a poor fit for the TPU memory
 system, so the TPU-native formulation is sort-based (SURVEY.md §7 step 1):
 
-    hash -> bucket id -> stable sort ROW INDICES by bucket -> offsets
+    hash -> bucket id -> ONE stable sort by bucket that carries every
+    1-D column as a value operand -> offsets
 
-The sort carries only (bucket id, row index) — two int32 lanes; data
-columns are never moved by the sort. ``to_padded`` then gathers each
-column directly from the ORIGINAL table through the composed index
-``order[bucket_offset + lane]``, so every column is touched by exactly
-one gather on its way into the collective (round 1 materialized a fully
-sorted table first and paid a second full gather in ``to_padded``; on
-this TPU random gathers at 10M rows cost ~100-300ms each — twice the
-sort itself — so the composition halves the partition's real cost).
+The sort moves the data: its keys are the bucket id (and the optional
+within-bucket order), its values every 1-D column and last a row-index
+iota, the row permutation ``order`` (XLA drops it on the CPU where
+nothing reads it; the TPU compiler makes a stable sort by adding such an
+index lane anyway, and reuses this one). Each bucket's rows
+then lie contiguously in every sorted column, so ``to_padded`` packs a
+destination's ``(capacity,)`` lane block with one ``dynamic_slice`` per
+bucket — a copy at HBM rate — and no per-row gather. The reason is
+docs/ROOFLINE.md §1: XLA's TPU gather is a serialized per-element loop
+(~9 ns per i32 element, ~21 ns per i64, whatever the index order), while
+a sort moves its value operands almost for free. On the four-chip
+(TPU v5e) 50M x 50M join the old formulation — a sort of
+(bucket, row index), then one gather per 32-bit half of each column
+through ``order[offset + lane]`` — spent 1.9 s of a 2.66 s call in those
+gathers; carrying the int64 key and payload through the sort instead
+costs about 20 ms per column and side at 12.5M rows a chip. Columns that are not 1-D (fixed-width string bytes,
+``(n, W) uint8``) cannot be sort operands; they alone are still gathered
+through ``order``.
 
 The result is exactly what the reference's all-to-all needs: rows
 grouped by destination bucket plus a per-bucket offset/count vector
@@ -26,7 +37,7 @@ a bigger pad or trigger the skew path.
 from __future__ import annotations
 
 import dataclasses
-from typing import Sequence
+from typing import Mapping, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -35,37 +46,60 @@ from distributed_join_tpu.ops.hashing import bucket_ids
 from distributed_join_tpu.table import Table
 
 
+# Below this many buckets, offsets come from a compare-and-sum over
+# the rows instead of a binary search: it costs rows x buckets
+# compares, so it is kept to few buckets.
+_COMPARE_ALL_BUCKETS = 64
+
+
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
 class PartitionedTable:
-    """A bucket-sorted VIEW of a table: the rows stay where they are;
-    ``order`` holds the stable bucket-sorted row permutation (invalid
-    rows sort after every real bucket).
+    """A table's rows grouped by bucket: the 1-D columns in
+    bucket-sorted order (values of the partition's sort), the rest
+    reached through the bucket-sorted row permutation. Invalid rows
+    sort after every real bucket.
 
     Attributes:
       source:  the original (unsorted) table.
       order:   (capacity,) int32 row permutation, bucket-sorted.
-      offsets: (n_buckets + 1,) int32; bucket b occupies
-               ``order[offsets[b] : offsets[b+1]]``.
+      offsets: (n_buckets + 1,) int32; bucket b occupies positions
+               ``offsets[b] : offsets[b+1]`` of ``order`` and of every
+               sorted column.
       counts:  (n_buckets,) int32 == diff(offsets).
+      sorted_columns: name -> the 1-D column ``name`` of ``source`` in
+               bucket-sorted order, produced by the sort itself (no
+               gather).
     """
 
     source: Table
     order: jax.Array
     offsets: jax.Array
     counts: jax.Array
+    sorted_columns: Mapping[str, jax.Array]
 
     @property
     def n_buckets(self) -> int:
         return self.counts.shape[0]
 
     @property
+    def gathered_columns(self) -> int:
+        """Columns packed by a gather through ``order`` (the ones the
+        sort could not carry: not 1-D)."""
+        return len(self.source.columns) - len(self.sorted_columns)
+
+    @property
     def table(self) -> Table:
-        """Materialized sorted view (one gather per column). The hot
-        path never calls this — ``to_padded`` gathers through ``order``
-        directly; it exists for tests/debugging."""
-        cols = {n: c[self.order] for n, c in self.source.columns.items()}
-        return Table(cols, self.source.valid[self.order])
+        """The bucket-sorted table: the sorted columns as they are, a
+        gather through ``order`` for the others. Valid rows form a
+        prefix (every invalid row sorts last)."""
+        cols = {
+            n: self.sorted_columns[n] if n in self.sorted_columns
+            else c[self.order]
+            for n, c in self.source.columns.items()
+        }
+        lane = jnp.arange(self.source.capacity, dtype=jnp.int32)
+        return Table(cols, lane < jnp.sum(self.counts))
 
     def to_padded(self, capacity: int, bucket_start: int = 0,
                   n_buckets: int | None = None):
@@ -76,6 +110,15 @@ class PartitionedTable:
         n_ranks buckets) at a time, exactly like the reference's batched
         pipeline (SURVEY.md §2 "Over-decomposition").
 
+        A sorted column's block for bucket ``b`` is one contiguous
+        ``dynamic_slice`` of ``capacity`` rows at ``offsets[b]``, taken
+        from the column padded at its end by ``capacity`` rows so that
+        no start is clamped (a clamped start would shift a bucket near
+        the table's end). Lanes past a bucket's count hold whatever
+        follows it (the next buckets' rows, invalid rows, the pad):
+        ``row_valid`` masks them. Columns that are not 1-D are gathered
+        through ``order`` instead.
+
         Returns (padded_columns: dict name -> (n_buckets, capacity) array,
         counts clipped to capacity, overflow: bool scalar — True iff some
         selected bucket exceeded the capacity and rows were dropped,
@@ -85,14 +128,25 @@ class PartitionedTable:
         offs = self.offsets[bucket_start : bucket_start + nb]
         counts = self.counts[bucket_start : bucket_start + nb]
         lane = jnp.arange(capacity, dtype=jnp.int32)
-        pos = offs[:, None] + lane[None, :]
         row_valid = lane[None, :] < counts[:, None]
-        cap_total = self.source.capacity
-        # Compose the bucket-slot -> sorted-position -> source-row maps
-        # so each data column is gathered ONCE, straight into its padded
-        # layout.
-        idx = self.order[jnp.clip(pos, 0, cap_total - 1)]
-        padded = {n: c[idx] for n, c in self.source.columns.items()}
+        idx = None
+        padded = {}
+        for n, c in self.source.columns.items():
+            if n in self.sorted_columns:
+                s = jnp.pad(self.sorted_columns[n], (0, capacity))
+                padded[n] = jnp.stack([
+                    jax.lax.dynamic_slice_in_dim(s, offs[j], capacity)
+                    for j in range(nb)
+                ])
+                continue
+            if idx is None:
+                # Compose the bucket-slot -> sorted-position ->
+                # source-row maps so the column is gathered once,
+                # straight into its padded layout.
+                pos = jnp.clip(offs[:, None] + lane[None, :], 0,
+                               self.source.capacity - 1)
+                idx = self.order[pos]
+            padded[n] = c[idx]
         overflow = jnp.any(counts > capacity)
         return padded, jnp.minimum(counts, capacity), overflow, row_valid
 
@@ -130,11 +184,11 @@ def radix_hash_partition(
     n_buckets = n_buckets * max(int(sub_buckets), 1)
     # Padding rows get bucket n_buckets so they sort after every real bucket.
     b = jnp.where(table.valid, b, jnp.int32(n_buckets))
-    # One stable 32-bit sort (bucket id key + int32 row index) — NOT
-    # jnp.argsort, whose x64-mode int64 iota operand would double every
-    # sort lane on TPU (emulated 64-bit).
-    n = b.shape[0]
-    operands = [b]
+    # One stable sort: 32-bit keys (bucket id, the optional order), the
+    # 1-D columns and an int32 row index as values — NOT jnp.argsort,
+    # whose x64-mode int64 iota would double that lane on TPU (emulated
+    # 64-bit).
+    keys = [b]
     if order_within is not None:
         oc = table.columns[order_within]
         if oc.ndim != 1 or not jnp.issubdtype(oc.dtype, jnp.integer):
@@ -142,17 +196,26 @@ def radix_hash_partition(
                 f"order_within column {order_within!r} must be a 1-D "
                 f"integer column, got ndim={oc.ndim} dtype={oc.dtype}"
             )
-        operands.append(-oc.astype(jnp.int32))
-    operands.append(jnp.arange(n, dtype=jnp.int32))
-    *sorted_ops, order = jax.lax.sort(
-        tuple(operands), num_keys=len(operands) - 1, is_stable=True
+        keys.append(-oc.astype(jnp.int32))
+    carried = [name for name, c in table.columns.items() if c.ndim == 1]
+    out = jax.lax.sort(
+        (*keys, *(table.columns[name] for name in carried),
+         jnp.arange(b.shape[0], dtype=jnp.int32)),
+        num_keys=len(keys), is_stable=True,
     )
+    order = out[-1]
+    sorted_columns = dict(zip(carried, out[len(keys):-1]))
+    # Bucket starts. For a few buckets, count the rows below each id in
+    # one compare-and-sum pass (no gather); for many, binary-search
+    # (its gathers touch n_buckets + 1 elements a step).
     offsets = jnp.searchsorted(
-        sorted_ops[0], jnp.arange(n_buckets + 1, dtype=jnp.int32),
+        out[0], jnp.arange(n_buckets + 1, dtype=jnp.int32),
         side="left",
+        method="compare_all" if n_buckets < _COMPARE_ALL_BUCKETS
+        else "scan",
     ).astype(jnp.int32)
     counts = jnp.diff(offsets)
-    return PartitionedTable(table, order, offsets, counts)
+    return PartitionedTable(table, order, offsets, counts, sorted_columns)
 
 
 def unpad(padded_columns, counts, capacity: int) -> Table:

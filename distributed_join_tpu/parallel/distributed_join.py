@@ -78,6 +78,21 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+def _record_partition(tape, pt, capacity: int) -> None:
+    """One side's partition counters (docs/OBSERVABILITY.md), where a
+    tape is given: valid rows partitioned, the tightest
+    per-(sender, destination)-bucket headroom under the shuffle
+    capacity contract (how close this sizing came to an overflow), and
+    the columns still packed by a gather (static)."""
+    if tape is not None:
+        tape.add("rows_partitioned",
+                 jnp.sum(pt.counts.astype(jnp.int64)))
+        tape.record_min("overflow_margin_min",
+                        jnp.int64(capacity)
+                        - jnp.max(pt.counts).astype(jnp.int64))
+        tape.add("gathered_columns", pt.gathered_columns)
+
+
 def _varwidth_cols(table: Table) -> list:
     """ALL 2-D uint8 columns with a '<name>#len' companion and
     4-aligned width — the columns the ragged shuffle ships
@@ -685,15 +700,9 @@ def make_join_step(
                 # EXPLAIN's segment-count prediction grades against a
                 # device-reported value, like the wire bytes.
                 tape.add("sort_segments", seg)
-                for t, pt, cap in ((tb, ptb, b_cap_s),
-                                   (tp, ptp, p_cap_s)):
-                    t.add("rows_partitioned",
-                          jnp.sum(pt.counts.astype(jnp.int64)))
-                    # Headroom under the FINE capacity contract.
-                    t.record_min(
-                        "overflow_margin_min",
-                        jnp.int64(cap)
-                        - jnp.max(pt.counts).astype(jnp.int64))
+            # Headroom under the FINE capacity contract.
+            _record_partition(tb, ptb, b_cap_s)
+            _record_partition(tp, ptp, p_cap_s)
             for b in range(k):
                 with jax.named_scope("shuffle"):
                     rb_cols, rb_counts, ovf_b = \
@@ -740,17 +749,8 @@ def make_join_step(
                 else None
             dtp = tape.scoped("probe.integrity") if with_integrity \
                 else None
-            if tape is not None:
-                for t, pt, cap in ((tb, ptb, b_cap), (tp, ptp, p_cap)):
-                    t.add("rows_partitioned",
-                          jnp.sum(pt.counts.astype(jnp.int64)))
-                    # Tightest per-(sender, destination)-bucket
-                    # headroom under the shuffle capacity contract —
-                    # how close this sizing came to an overflow.
-                    t.record_min(
-                        "overflow_margin_min",
-                        jnp.int64(cap)
-                        - jnp.max(pt.counts).astype(jnp.int64))
+            _record_partition(tb, ptb, b_cap)
+            _record_partition(tp, ptp, p_cap)
             for b in range(k):
                 with jax.named_scope("shuffle"):
                     recv_build, ovf_b = _batch_shuffle(
@@ -895,14 +895,8 @@ def _make_join_agg_step(comm, spec, *, keys, k,
                 else None
             dtp = tape.scoped("probe.integrity") if with_integrity \
                 else None
-            if tape is not None:
-                for t_, pt, cap in ((tb, ptb, b_cap), (tp, ptp, p_cap)):
-                    t_.add("rows_partitioned",
-                           jnp.sum(pt.counts.astype(jnp.int64)))
-                    t_.record_min(
-                        "overflow_margin_min",
-                        jnp.int64(cap)
-                        - jnp.max(pt.counts).astype(jnp.int64))
+            _record_partition(tb, ptb, b_cap)
+            _record_partition(tp, ptp, p_cap)
             for b in range(k):
                 with jax.named_scope("shuffle"):
                     recv_build, ovf_b = _batch_shuffle(
@@ -1191,13 +1185,7 @@ def make_probe_join_step(
             tp = tape.scoped("probe") if tape is not None else None
             dtp = tape.scoped("probe.integrity") if with_integrity \
                 else None
-            if tape is not None:
-                tp.add("rows_partitioned",
-                       jnp.sum(ptp.counts.astype(jnp.int64)))
-                tp.record_min(
-                    "overflow_margin_min",
-                    jnp.int64(p_cap)
-                    - jnp.max(ptp.counts).astype(jnp.int64))
+            _record_partition(tp, ptp, p_cap)
             for b in range(k):
                 with jax.named_scope("shuffle"):
                     recv_probe, ovf_p = _batch_shuffle(
@@ -1316,13 +1304,7 @@ def _make_probe_agg_step(comm, spec, *, keys, k,
             tp = tape.scoped("probe") if tape is not None else None
             dtp = tape.scoped("probe.integrity") if with_integrity \
                 else None
-            if tape is not None:
-                tp.add("rows_partitioned",
-                       jnp.sum(ptp.counts.astype(jnp.int64)))
-                tp.record_min(
-                    "overflow_margin_min",
-                    jnp.int64(p_cap)
-                    - jnp.max(ptp.counts).astype(jnp.int64))
+            _record_partition(tp, ptp, p_cap)
             for b in range(k):
                 with jax.named_scope("shuffle"):
                     recv_probe, ovf_p = _batch_shuffle(

@@ -151,12 +151,13 @@ def shuffle_padded_compressed(
             recv_cols[name] = a2a(col)
             continue
 
-        # Padding slots hold clipped-gather garbage (possibly a mix of
-        # a neighboring bucket's rows and the table's zero pad rows) —
-        # a block mixing 0 with large-magnitude real values would blow
-        # the residual span for data whose REAL residuals are tiny.
-        # Fill them with the bucket's last valid row instead (residual
-        # 0 against a real frame, the codec's own padding trick).
+        # Padding slots hold whatever follows the bucket in the sorted
+        # column (the next buckets' rows, invalid rows, the slice pad's
+        # zeros) — a block mixing 0 with large-magnitude real values
+        # would blow the residual span for data whose REAL residuals
+        # are tiny. Fill them with the bucket's last valid row instead
+        # (residual 0 against a real frame, the codec's own padding
+        # trick).
         fill = col[jnp.arange(n_ranks), jnp.maximum(counts - 1, 0)]
         col = jnp.where(row_valid, col, fill[:, None])
 
@@ -612,10 +613,11 @@ def shuffle_ragged(
         tape.add("rows_shuffled", rows_sent)
         tape.add("rows_received", total_recv.astype(jnp.int64))
         tape.add("wire_bytes", rows_sent * row_bytes)
-    # One gather per column materializes the bucket-sorted layout the
-    # input offsets point into (no padding, unlike to_padded). The
-    # varwidth columns go LAST: the extra ones need their received
-    # "#len" companion to reconstruct the sender-side permutation.
+    # The bucket-sorted layout the input offsets point into (no
+    # padding, unlike to_padded): the partition's sort carried the 1-D
+    # columns; only the 2-D ones are gathered. The varwidth columns go
+    # LAST: the extra ones need their received "#len" companion to
+    # reconstruct the sender-side permutation.
     sorted_table = pt.table
     out_cols = {}
     for name, col in sorted_table.columns.items():

@@ -277,6 +277,7 @@ def profile_join_stages(comm, build, probe, key="key", repeats: int = 3,
     )
     from distributed_join_tpu.parallel.distributed_join import (
         JOIN_SHARDED_OUT,
+        _record_partition,
         _round_up,
         _varwidth_cols,
         make_join_step,
@@ -388,14 +389,8 @@ def profile_join_stages(comm, build, probe, key="key", repeats: int = 3,
         ptp = radix_hash_partition(probe_local, keys, nb,
                                    sub_buckets=sort_seg)
         tape.add("sort_segments", sort_seg)
-        for scope, pt, cap in (("build", ptb, seg_b_cap),
-                               ("probe", ptp, seg_p_cap)):
-            t = tape.scoped(scope)
-            t.add("rows_partitioned",
-                  jnp.sum(pt.counts.astype(jnp.int64)))
-            t.record_min("overflow_margin_min",
-                         jnp.int64(cap)
-                         - jnp.max(pt.counts).astype(jnp.int64))
+        _record_partition(tape.scoped("build"), ptb, seg_b_cap)
+        _record_partition(tape.scoped("probe"), ptp, seg_p_cap)
         out = {}
         overflow = jnp.bool_(False)
         for side, pt, cap in (("build", ptb, seg_b_cap),
@@ -480,22 +475,16 @@ def profile_join_stages(comm, build, probe, key="key", repeats: int = 3,
         tape = telemetry.MetricsTape()
         ptb = radix_hash_partition(build_local, keys, nb)
         ptp = radix_hash_partition(probe_local, keys, nb)
-        for scope, pt, cap in (("build", ptb, b_cap),
-                               ("probe", ptp, p_cap)):
-            t = tape.scoped(scope)
-            t.add("rows_partitioned",
-                  jnp.sum(pt.counts.astype(jnp.int64)))
-            t.record_min("overflow_margin_min",
-                         jnp.int64(cap)
-                         - jnp.max(pt.counts).astype(jnp.int64))
+        _record_partition(tape.scoped("build"), ptb, b_cap)
+        _record_partition(tape.scoped("probe"), ptp, p_cap)
         out = {}
         overflow = jnp.bool_(False)
         for side, pt, cap in (("build", ptb, b_cap),
                               ("probe", ptp, p_cap)):
             if mode == "ragged":
-                # The sorted-layout materialization (one gather per
-                # column) is partition work per the cost model, as is
-                # to_padded's gather below.
+                # The sorted layout (the partition sort's own output;
+                # a gather for 2-D columns) is partition work per the
+                # cost model, as is to_padded's packing below.
                 st = pt.table
                 for cname, c in st.columns.items():
                     out[f"{side}.col.{cname}"] = c
@@ -533,6 +522,8 @@ def profile_join_stages(comm, build, probe, key="key", repeats: int = 3,
                     order=jnp.arange(rows, dtype=jnp.int32),
                     offsets=payload[f"{side}.offsets"],
                     counts=payload[f"{side}.counts"],
+                    sorted_columns={cname: c for cname, c in cols.items()
+                                    if c.ndim == 1},
                 )
                 for b in range(k):
                     recv, ovf = shuffle_ragged(
