@@ -107,7 +107,8 @@ def _compact_kernel(base_ref, q_ref, pm_ref, v_ref, out_hbm, stage,
 
 
 def stream_compact(mask: jax.Array, pos: jax.Array, cols, capacity: int,
-                   block: int | None = None, interpret: bool = False):
+                   block: int | None = None, interpret: bool = False,
+                   name: str = "compact_mxu"):
     """Order-preserving masked compaction of k uint64 columns.
 
     mask: (n,) bool — survivors.
@@ -118,7 +119,8 @@ def stream_compact(mask: jax.Array, pos: jax.Array, cols, capacity: int,
           dropped (a suffix, by monotonicity).
 
     Returns k uint64 arrays of length ``capacity``; slots >= the
-    survivor count are undefined.
+    survivor count are undefined. ``name`` is the kernel's name, which
+    a profile reads.
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -170,7 +172,7 @@ def stream_compact(mask: jax.Array, pos: jax.Array, cols, capacity: int,
             functools.partial(
                 _compact_kernel, block=block, chunk=chunk, ck=ck, w=w
             ),
-            name="compact_mxu",
+            name=name,
             grid=(nblocks,),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -148,11 +148,13 @@ def _compact_kernel(base8_ref, q_ref, *refs, block: int, nplanes: int):
 def plane_compact_stacked(stacked: jax.Array, mask: jax.Array,
                           pos: jax.Array, capacity: int,
                           block: int = 32768,
-                          interpret: bool = False):
+                          interpret: bool = False,
+                          name: str = "compact_planes"):
     """Compact P u32 planes (stacked (P, n)) to ``capacity`` slots.
 
     mask: (n,) bool survivors; pos: (n,) int32 == cumsum(mask)-1.
     Returns (P, capacity); slots >= the survivor count are undefined.
+    ``name`` is the kernel's name, which a profile reads.
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -220,7 +222,7 @@ def plane_compact_stacked(stacked: jax.Array, mask: jax.Array,
             functools.partial(
                 _compact_kernel, block=block, nplanes=P
             ),
-            name="compact_planes",
+            name=name,
             grid=(nblocks,),
             in_specs=[
                 pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -239,7 +241,8 @@ def plane_compact_stacked(stacked: jax.Array, mask: jax.Array,
 
 
 def plane_stream_compact(mask, pos, cols, capacity: int,
-                         block: int = 32768, interpret: bool = False):
+                         block: int = 32768, interpret: bool = False,
+                         name: str = "compact_planes"):
     """Drop-in for ops/compact_pallas.stream_compact: uint64 columns
     in, uint64 columns (length ``capacity``) out."""
     planes = []
@@ -248,7 +251,7 @@ def plane_stream_compact(mask, pos, cols, capacity: int,
     stacked = jnp.stack(planes)
     outp = plane_compact_stacked(
         stacked, mask, pos.astype(jnp.int32), capacity,
-        block=block, interpret=interpret,
+        block=block, interpret=interpret, name=name,
     )
     return [
         merge_u64(outp[2 * i], outp[2 * i + 1])

@@ -518,7 +518,7 @@ def _tiled_output_launch(n_blocks, block, tile_bytes, launch, merge):
 
 
 def _expand_gather_b8(S, cols, out_capacity, block, interpret, lo,
-                      build_cols, window=None):
+                      build_cols, window=None, name_prefix=""):
     """v3 build-mode wrapper; see _expand_kernel_b8."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -658,7 +658,7 @@ def _expand_gather_b8(S, cols, out_capacity, block, interpret, lo,
                     _expand_kernel_b8, block=block, chunk=chunk,
                     ck8=ck8, ckb8=ckb8, wr=wr, w1w=w1w, w2w=w2w,
                 ),
-                name="expand_b8",
+                name=f"{name_prefix}expand_b8",
                 grid=(qb,),
                 in_specs=[
                     pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -703,7 +703,7 @@ def expand_gather(S: jax.Array, cols: Sequence[jax.Array],
                   interpret: bool = False,
                   lo: Optional[jax.Array] = None,
                   build_cols: Optional[Sequence[jax.Array]] = None,
-                  window: int | None = None):
+                  window: int | None = None, name_prefix: str = ""):
     """For each output slot j in [0, out_capacity): find the covering
     record r = max{r : S[r] <= j} and return each column's value at r,
     plus the run-start slot ``start_b[j] = S[r]``.
@@ -734,6 +734,9 @@ def expand_gather(S: jax.Array, cols: Sequence[jax.Array],
     DMA tiling; the kernel proves window offsets divisible by it);
     interpret mode accepts any block with block % chunk == 0 (the
     chunked loops; _window_widths handles the 128-lane rounding).
+
+    ``name_prefix`` goes before the kernel's name (``expand_b8`` on the
+    build path, ``expand`` otherwise), which a profile reads.
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -751,7 +754,7 @@ def expand_gather(S: jax.Array, cols: Sequence[jax.Array],
         # independently of the block (_window_widths).
         return _expand_gather_b8(
             S, cols, out_capacity, block, interpret, lo, build_cols,
-            window=window,
+            window=window, name_prefix=name_prefix,
         )
     k = len(cols)
     m = S.shape[0]
@@ -830,7 +833,7 @@ def expand_gather(S: jax.Array, cols: Sequence[jax.Array],
                     _expand_kernel, block=block, chunk=chunk,
                     ck=ck, srow=srow,
                 ),
-                name="expand",
+                name=f"{name_prefix}expand",
                 grid=(qb,),
                 in_specs=[
                     pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -185,7 +185,8 @@ def _from_u64_lane(c64: jax.Array, dt):
     raise TypeError(dt)
 
 
-def _expand_records(S, recs: dict, out_capacity: int, j, cfg):
+def _expand_records(S, recs: dict, out_capacity: int, j, cfg,
+                    kernel_prefix: str = ""):
     """Broadcast each record's values down its output run (the XLA
     join path's expansion; the kernel pipeline's lives in
     _join_kernel_path with the fused build-side materialization).
@@ -222,6 +223,7 @@ def _expand_records(S, recs: dict, out_capacity: int, j, cfg):
             rec_outs, start_b = expand_gather(
                 S, [lanes[nm] for nm in names], out_capacity,
                 block=cfg.block, interpret=interpret,
+                name_prefix=kernel_prefix,
             )
             out_vals = {
                 nm: _from_u64_lane(rec_outs[i], recs[nm].dtype)
@@ -348,7 +350,7 @@ def _warn_xla_fallback(interpret: bool, why: str) -> None:
 
 def _join_kernel_path(build, probe, keys, b1d, b2d, p1d, p2d,
                       build_payload, probe_payload, out_capacity,
-                      interpret, cfg) -> JoinResult:
+                      interpret, cfg, kernel_prefix="") -> JoinResult:
     """The TPU pipeline: ONE value-carrying merged sort, the fused
     scan kernel (ops/scan_pallas.py — including the MATCHED-build
     machinery), two streaming compactions (ops/compact_pallas.py: the
@@ -374,6 +376,9 @@ def _join_kernel_path(build, probe, keys, b1d, b2d, p1d, p2d,
     # one-hot matmul kernel. Config is resolved at TRACE time.
     if cfg.use_plane_compact(interpret):
         stream_compact = plane_stream_compact  # noqa: F811
+        compact_name = kernel_prefix + "compact_planes"
+    else:
+        compact_name = kernel_prefix + "compact_mxu"
 
     nb, npr = build.capacity, probe.capacity
     n = nb + npr
@@ -452,7 +457,8 @@ def _join_kernel_path(build, probe, keys, b1d, b2d, p1d, p2d,
             changed = changed | (sk != prev)
         first = changed | (iota == 0)
 
-        sc = join_scans(stag, first, interpret=interpret)
+        sc = join_scans(stag, first, interpret=interpret,
+                        name_prefix=kernel_prefix)
         cnt = sc["cnt"]
         # start_out is int32: past 2**31 total matches it wraps, S is no
         # longer sorted, and searchsorted/build_windows_ok below operate on
@@ -482,7 +488,7 @@ def _join_kernel_path(build, probe, keys, b1d, b2d, p1d, p2d,
         rec_names = list(rec_lanes)
         compacted = dict(zip(rec_names, stream_compact(
             is_rec, sc["rec_pos"], [rec_lanes[nm] for nm in rec_names],
-            out_capacity, interpret=interpret,
+            out_capacity, interpret=interpret, name=compact_name,
         )))
         kept = jnp.minimum(rec_total, jnp.int32(out_capacity))
         j = jnp.arange(out_capacity, dtype=jnp.int32)
@@ -506,6 +512,7 @@ def _join_kernel_path(build, probe, keys, b1d, b2d, p1d, p2d,
         matched = sc["matched"] != 0
         pack = stream_compact(
             matched, sc["mb_pos"], pack_lanes, nb, interpret=interpret,
+            name=compact_name,
         ) if pack_names else []
 
     with jax.named_scope("expand"):
@@ -519,13 +526,14 @@ def _join_kernel_path(build, probe, keys, b1d, b2d, p1d, p2d,
                 return expand_gather(
                     S, cols_list, out_capacity, block=cfg.block,
                     interpret=interpret, lo=lo_rec, build_cols=pack,
-                    window=cfg.window,
+                    window=cfg.window, name_prefix=kernel_prefix,
                 )
 
             def _fallback(_):
                 outs2, sb2 = expand_gather(
                     S, cols_list + [compacted["__lo"]], out_capacity,
                     block=cfg.block, interpret=interpret,
+                    name_prefix=kernel_prefix,
                 )
                 rank2 = outs2[-1].astype(jnp.int32) + (j - sb2)
                 safe = jnp.clip(rank2, 0, max(nb - 1, 0))
@@ -541,7 +549,7 @@ def _join_kernel_path(build, probe, keys, b1d, b2d, p1d, p2d,
         else:
             rec_outs, start_b = expand_gather(
                 S, cols_list, out_capacity, block=cfg.block,
-                interpret=interpret,
+                interpret=interpret, name_prefix=kernel_prefix,
             )
             build_vals_u64 = {}
         rec_vals_u64 = dict(zip(rec_value_names, rec_outs))
@@ -594,6 +602,7 @@ def sort_merge_inner_join(
     kernel_config: Optional["KernelConfig"] = None,
     join_type: str = "inner",
     _internal: Sequence[str] = (),
+    kernel_prefix: str = "",
 ) -> JoinResult:
     """Join ``build`` and ``probe`` on equality of ``key`` — a
     column name or a sequence of names (composite key). A key column
@@ -621,6 +630,9 @@ def sort_merge_inner_join(
 
     ``kernel_config`` (ops/kernel_config.KernelConfig) selects the
     Pallas kernel paths; None reads the DJTPU_* env fallbacks.
+    ``kernel_prefix`` goes before the names of the Pallas kernels it
+    runs (``join_scan_r``, ``compact_planes``, ``expand_b8``, ...), so
+    that a profile tells this join's kernels from another's.
     """
     if join_type not in JOIN_TYPES:
         raise ValueError(
@@ -653,7 +665,7 @@ def sort_merge_inner_join(
             b2, p2, keys2, out_capacity,
             build_payload=bp, probe_payload=pp,
             kernel_config=kernel_config, join_type=join_type,
-            _internal=allowed,
+            _internal=allowed, kernel_prefix=kernel_prefix,
         )
         out = patch_string_lengths(
             rebuild_string_keys(res.table, spec, keys), keys, join_type
@@ -745,7 +757,7 @@ def sort_merge_inner_join(
     if use_kernel:
         return _join_kernel_path(
             build, probe, keys, b1d, b2d, p1d, p2d, build_payload,
-            probe_payload, out_capacity, interpret, cfg,
+            probe_payload, out_capacity, interpret, cfg, kernel_prefix,
         )
 
     with jax.named_scope("prepare"):
@@ -949,7 +961,8 @@ def sort_merge_inner_join(
         #    _join_kernel_path; this path serves CPU, f64 columns, and
         #    blocks past the f32-exact rank range.
         j = jnp.arange(out_capacity, dtype=jnp.int32)
-        out_vals, start_b = _expand_records(S, recs, out_capacity, j, cfg)
+        out_vals, start_b = _expand_records(S, recs, out_capacity, j, cfg,
+                                            kernel_prefix)
         bm = pm = None
         if join_type != "inner":
             bm = out_vals.pop("__bm") != 0

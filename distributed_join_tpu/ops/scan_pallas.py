@@ -202,7 +202,7 @@ def _scan_f_kernel(tag_ref, first_ref, matched_ref, cnt_ref, so_ref,
 
 
 def join_scans(tag: jax.Array, first: jax.Array,
-               interpret: bool = False):
+               interpret: bool = False, name_prefix: str = ""):
     """All merged-domain scans of the sort-merge join, fused.
 
     tag:   (n,) int8 — 0 build, 1 probe, 2 padding (ops/join.py step 2).
@@ -214,6 +214,9 @@ def join_scans(tag: jax.Array, first: jax.Array,
     (cumsum(is_rec)-1), ``matched`` (0/1 matched-build flag),
     ``mb_pos`` (cumsum(matched)-1). Totals are the last elements + 1
     of the *_pos arrays (position scans cover every element).
+
+    ``name_prefix`` goes before the two kernels' names
+    (``join_scan_r``, ``join_scan_f``), which a profile reads.
     """
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -256,7 +259,7 @@ def join_scans(tag: jax.Array, first: jax.Array,
     with jax.enable_x64(False):
         matched2 = pl.pallas_call(
             _scan_r_kernel,
-            name="join_scan_r",
+            name=f"{name_prefix}join_scan_r",
             grid=(nblocks,),
             in_specs=[rspec, rspec],
             out_specs=rspec,
@@ -270,7 +273,7 @@ def join_scans(tag: jax.Array, first: jax.Array,
 
         outs = pl.pallas_call(
             _scan_f_kernel,
-            name="join_scan_f",
+            name=f"{name_prefix}join_scan_f",
             grid=(nblocks,),
             in_specs=[spec, spec, spec],
             out_specs=[spec] * 5,

@@ -78,6 +78,12 @@ def _round_up(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+def _bucket_capacity(rows: int, n_buckets: int, factor: float) -> int:
+    """Rows of one (sender, destination) bucket of the padded shuffle:
+    a rank's ``rows`` over the buckets, times the capacity factor."""
+    return _round_up(int(math.ceil(rows / n_buckets * factor)), 8)
+
+
 def _record_partition(tape, pt, capacity: int) -> None:
     """One side's partition counters (docs/OBSERVABILITY.md), where a
     tape is given: valid rows partitioned, the tightest
@@ -345,7 +351,11 @@ def make_join_step(
     Heavy-hitter mass above these (Zipf alpha >= ~1.4 puts ~90% of
     probe rows in the top keys) overflows and is caught by the flag /
     ``auto_retry`` doubling; size them explicitly for known-heavy
-    workloads.
+    workloads (``distributed_inner_join`` sizes them from the input
+    when the caller sets no ``skew_threshold``). The HH join's Pallas
+    kernels carry an ``hh_`` prefix (``hh_join_scan_f``,
+    ``hh_compact_planes``, ``hh_expand_b8``, ...), and the HH probe and
+    build blocks are compacted by ``hh_extract``.
 
     Telemetry (docs/OBSERVABILITY.md): ``with_metrics=True`` makes the
     step return ``(JoinResult, telemetry.Metrics)`` — device-side
@@ -567,8 +577,8 @@ def make_join_step(
             nm for _, wns, _ in str_spec for nm in wns
         )
         b_rows, p_rows = build_local.capacity, probe_local.capacity
-        b_cap = _round_up(int(math.ceil(b_rows / nb * shuffle_capacity_factor)), 8)
-        p_cap = _round_up(int(math.ceil(p_rows / nb * shuffle_capacity_factor)), 8)
+        b_cap = _bucket_capacity(b_rows, nb, shuffle_capacity_factor)
+        p_cap = _bucket_capacity(p_rows, nb, shuffle_capacity_factor)
         if out_rows_per_rank is not None:
             out_cap = _round_up(int(math.ceil(out_rows_per_rank / k)), 8)
         else:
@@ -629,7 +639,7 @@ def make_join_step(
                     hh_out_capacity or max(p_rows // 4, 1024),
                     build_payload=bpay, probe_payload=ppay,
                     kernel_config=kernel_config,
-                    _internal=sk_names,
+                    _internal=sk_names, kernel_prefix="hh_",
                 )
                 parts.append(hh_res.table)
                 total = total + hh_res.total.astype(jnp.int64)
@@ -1466,6 +1476,47 @@ def resolve_join_ladder(build, probe, n_ranks: int, opts: dict,
     )
 
 
+def _resolve_skew(comm, build, probe, key, opts: dict) -> None:
+    """The skew path, resolved from the input (mutates ``opts``).
+
+    An explicit ``skew_threshold`` keeps its meaning, except 0, which
+    forces the plain path. Where the caller set none, on a mesh of
+    more than one rank and for the shapes the skew sidecar takes (an
+    inner join, the flat sort, no aggregate), a detection program
+    decides (:func:`..skew.resolve`) under the ``entry.skew_resolve``
+    span, whose arguments say what it found; turned on, it sets the
+    threshold and the HH blocks the caller left unset. Off, ``opts``
+    stays as given, so the program is the one the caller's options
+    name."""
+    threshold = opts.get("skew_threshold")
+    if threshold == 0:
+        opts.pop("skew_threshold")
+        return
+    n = comm.n_ranks
+    if (threshold is not None or n == 1
+            or opts.get("join_type", "inner") != "inner"
+            or opts.get("sort_mode", "flat") != "flat"
+            or opts.get("aggregate") is not None):
+        return
+    from distributed_join_tpu.parallel import skew
+
+    nb = opts.get("over_decomposition", 1) * n
+    hh_slots = opts.get("hh_slots", DEFAULT_HH_SLOTS)
+    factor = opts.get("shuffle_capacity_factor",
+                      DEFAULT_SHUFFLE_CAPACITY_FACTOR)
+    with telemetry.span("entry.skew_resolve"):
+        res = skew.resolve(
+            comm, build, probe, key, n_buckets=nb,
+            bucket_capacity=_bucket_capacity(probe.capacity // n, nb,
+                                             factor),
+            hh_slots=hh_slots,
+            hh_build_floor=hh_slots * HH_BUILD_SLOTS_PER_HH)
+        telemetry.note(**res.as_args())
+    for name, value in res.options.items():
+        if opts.get(name) is None:
+            opts[name] = value
+
+
 def distributed_inner_join(
     build: Table,
     probe: Table,
@@ -1493,6 +1544,16 @@ def distributed_inner_join(
     host-side ``retry_report`` attribute (:class:`..faults.RetryReport`
     — which capacities doubled, why, per attempt), which the benchmark
     drivers embed in their JSON records.
+
+    Skew (BASELINE config 3): where ``opts`` set no ``skew_threshold``,
+    an inner join with the flat sort and no aggregate on a mesh of more
+    than one rank first runs a small detection program
+    (:func:`..skew.resolve`, span ``entry.skew_resolve``). It turns the
+    heavy-hitter path on only where the heavy keys would overflow the
+    padded shuffle, with blocks sized from what it measured; otherwise
+    the program is the one the options name. ``skew_threshold=0``
+    forces the plain path; any other value keeps its meaning
+    (:func:`make_join_step`).
 
     ``verify_integrity``: compute in-graph wire digests
     (parallel/integrity.py) and verify every (src, dst) pair host-side
@@ -1553,8 +1614,8 @@ def distributed_inner_join(
 
     # Host spans (telemetry.span: a TraceAnnotation, plus a sink event
     # with a session) split each call on the profiler's clock:
-    # prepare -> resolve (-> compile on a program-cache miss) ->
-    # dispatch -> fetch_overflow -> finish.
+    # prepare (-> skew_resolve) -> resolve (-> compile on a
+    # program-cache miss) -> dispatch -> fetch_overflow -> finish.
     with telemetry.span("entry.prepare"):
         tuned = None
         if tuner is not None:
@@ -1570,6 +1631,7 @@ def distributed_inner_join(
         probe = probe.pad_to(_round_up(probe.capacity, n))
         if hasattr(comm, "device_put_sharded"):
             build, probe = comm.device_put_sharded((build, probe))
+    _resolve_skew(comm, build, probe, key, opts)
 
     with telemetry.span("entry.resolve"):
         ladder = resolve_join_ladder(

@@ -32,11 +32,20 @@ approximate — a key spread thin below every rank's local top-K can be
 missed — but classification consistency, not accuracy, is what
 correctness needs; a missed moderately-hot key just pays normal-path
 padding.
+
+Where the caller sets no ``skew_threshold``, ``distributed_inner_join``
+decides from the input (:func:`resolve`): a small detection program
+samples each rank's probe keys, estimates the fullest shuffle bucket
+with and without the heavy keys, and counts the heavy keys' build rows.
+The path turns on only where the heavy keys would overflow the padded
+shuffle and diverting them relieves it; its blocks are then sized from
+what was measured.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 
 import jax
@@ -44,8 +53,14 @@ import jax.numpy as jnp
 from jax import lax
 
 from distributed_join_tpu.ops.join import _dtype_sentinel_max
-from distributed_join_tpu.parallel.communicator import Communicator
+from distributed_join_tpu.parallel.communicator import Communicator, _pvary
 from distributed_join_tpu.table import Table
+
+# The entry's resolution (:func:`resolve`).
+RESOLVE_SAMPLE_ROWS = 1 << 15  # probe rows a rank samples
+RESOLVE_THRESHOLD = 0.001      # the driver's measured sweep default
+RESOLVE_SLACK = 1.25           # an HH block's headroom over its estimate
+RESOLVE_GRID = 8               # HH blocks round up to 1/8 of rank rows
 
 
 @jax.tree_util.register_dataclass
@@ -134,6 +149,16 @@ def local_top_keys(keys: jax.Array, valid: jax.Array, k: int):
     return top_keys, top_counts
 
 
+def _sample_index(m: int, n: int) -> jax.Array:
+    """``m`` quasi-random row positions of ``n``. The odd multiplier
+    cycles positions through all residues of every power-of-two
+    period: near-uniform coverage of any periodic layout. Collisions
+    (gcd(C, n) > 1) repeat a few rows — harmless for approximate
+    counting."""
+    return ((jnp.arange(m, dtype=jnp.int64) * jnp.int64(2654435761))
+            % jnp.int64(n)).astype(jnp.int32)
+
+
 def global_heavy_hitters(
     comm: Communicator,
     keys: jax.Array,
@@ -163,14 +188,7 @@ def global_heavy_hitters(
     from who sampled what."""
     n = keys.shape[0]
     if sample > 1 and n >= 64 * k * sample:
-        m = n // sample
-        # odd multiplier -> positions cycle through all residues of
-        # every power-of-two period; near-uniform coverage of any
-        # periodic layout. Collisions (gcd(C, n) > 1) repeat a few
-        # rows — harmless for approximate counting.
-        idx = ((
-            jnp.arange(m, dtype=jnp.int64) * jnp.int64(2654435761)
-        ) % jnp.int64(n)).astype(jnp.int32)
+        idx = _sample_index(n // sample, n)
         keys_d = keys[idx]
         valid_d = valid[idx]
         # A threshold below ``sample`` truncates to 0, which would make
@@ -232,8 +250,10 @@ def mark_heavy(keys: jax.Array, hh: HeavyHitters) -> jax.Array:
 def extract_prefix(table: Table, sel: jax.Array, capacity: int,
                    kernel_config=None):
     """Stable-compact rows where ``sel`` into a static-capacity Table;
-    returns (extracted, count, overflow). ``capacity`` may exceed the
-    table's row count (extra slots are padding).
+    returns (extracted, count, overflow). Where ``capacity`` is at
+    least the table's row count, the block is the table itself with
+    ``sel`` as its validity (padded to ``capacity``): the join reads a
+    mask as it reads a prefix, so nothing is sorted or gathered.
 
     On TPU the selected row INDICES are packed by the streaming
     log-shift compaction kernel (ops/compact_planes.py — the round-3
@@ -245,9 +265,12 @@ def extract_prefix(table: Table, sel: jax.Array, capacity: int,
     from distributed_join_tpu.ops.kernel_config import resolve
 
     n = sel.shape[0]
+    count = jnp.sum(sel.astype(jnp.int32))
+    if capacity >= n:
+        return (Table(table.columns, sel).pad_to(capacity), count,
+                jnp.bool_(False))
     cfg = resolve(kernel_config)
     use_kernel, interpret = cfg.expand_enabled()
-    count = jnp.sum(sel.astype(jnp.int32))
     lane = jnp.arange(capacity, dtype=jnp.int32)
     if use_kernel and not interpret and n >= 2 * capacity:
         from distributed_join_tpu.ops.compact_pallas import stream_compact
@@ -263,7 +286,8 @@ def extract_prefix(table: Table, sel: jax.Array, capacity: int,
         iota64 = jnp.arange(n, dtype=jnp.uint64)
         kw = {"block": cfg.block} if cfg.block else {}
         (packed_idx,) = compact(sel, pos, [iota64], capacity,
-                                interpret=interpret, **kw)
+                                interpret=interpret, name="hh_extract",
+                                **kw)
         # Slots >= the survivor count are UNDEFINED in the kernel's
         # contract — clamp before gathering; `valid` masks them.
         idx = jnp.clip(packed_idx.astype(jnp.int32), 0, n - 1)
@@ -276,7 +300,7 @@ def extract_prefix(table: Table, sel: jax.Array, capacity: int,
         )
         idx = order[jnp.minimum(lane, n - 1)]
     cols = {name: c[idx] for name, c in table.columns.items()}
-    valid = (lane < jnp.minimum(count, capacity)) & (lane < n)
+    valid = lane < jnp.minimum(count, capacity)
     return Table(cols, valid), count, count > capacity
 
 
@@ -292,3 +316,171 @@ def broadcast_heavy_build(
     cols = {n: comm.all_gather(c) for n, c in local.columns.items()}
     valid = comm.all_gather(local.valid)
     return Table(cols, valid), comm.psum(overflow.astype(jnp.int32)) > 0
+
+
+# -- the entry's resolution from the input ----------------------------
+
+
+class _AxisGather:
+    """The one collective :func:`global_heavy_hitters` asks of a
+    communicator, on a mesh axis: the detection program runs beside
+    the join's programs, outside any communicator wrapper that counts
+    or perturbs them."""
+
+    def __init__(self, axis_name):
+        self.axis_name = axis_name
+
+    def all_gather(self, x):
+        return lax.all_gather(x, self.axis_name, axis=0, tiled=True)
+
+
+@dataclasses.dataclass(frozen=True)
+class Resolution:
+    """What the detection program measured, in probe rows of the
+    fullest rank, and the join options resolved from it (empty where
+    the path stays off)."""
+
+    on: bool
+    bucket_capacity: int       # the padded shuffle's per-bucket rows
+    fullest_bucket: int        # estimated rows of the fullest bucket
+    hh_probe_rows: int         # estimated heavy-key probe rows
+    options: dict
+
+    def as_args(self) -> dict:
+        """The ``entry.skew_resolve`` span's arguments."""
+        return {"on": int(self.on),
+                "hh_probe_rows": self.hh_probe_rows,
+                "fullest_bucket": self.fullest_bucket,
+                "bucket_capacity": self.bucket_capacity,
+                **{k: self.options.get(k, 0) for k in (
+                    "hh_probe_capacity", "hh_build_capacity",
+                    "hh_out_capacity")}}
+
+
+@functools.lru_cache(maxsize=32)
+def _detection_program(mesh, axis_name, keys: tuple, n_buckets: int,
+                       bucket_capacity: int, sample_rows: int,
+                       hh_slots: int, threshold: int):
+    """One SPMD program over ``mesh`` (cached per static sizing; jit
+    caches per shape): ``(build, probe)`` -> a replicated int64 vector
+    ``[on, fullest, hh_probe, hh_out, hh_build,
+    max_build_rows_per_key]``: the fullest bucket and the heavy probe
+    and output rows in sampled rows of the fullest rank, the heavy
+    build rows of the fullest rank."""
+    from jax.sharding import PartitionSpec as P
+
+    from distributed_join_tpu.ops.hashing import hash_columns
+    from distributed_join_tpu.utils.strings import prepare_string_key_join
+
+    def pmax(x):
+        # A TPU all-reduces 64-bit integers by sum alone: the maximum
+        # goes over 32 bits, saturated (counts of one rank fit).
+        x = jnp.minimum(x, jnp.iinfo(jnp.int32).max).astype(jnp.int32)
+        return lax.pmax(x, axis_name).astype(jnp.int64)
+
+    def step(build, probe):
+        # String keys hash as the step hashes them: packed words.
+        build, probe, keys_eff, *_ = prepare_string_key_join(
+            build, probe, list(keys), None, None)
+        p_rows = probe.capacity
+        cols = [probe.columns[k] for k in keys_eff]
+        valid = probe.valid
+        if sample_rows < p_rows:
+            idx = _sample_index(sample_rows, p_rows)
+            cols = [c[idx] for c in cols]
+            valid = valid[idx]
+        h = hash_columns(cols)
+        hh = global_heavy_hitters(_AxisGather(axis_name), h, valid,
+                                  hh_slots, threshold=threshold,
+                                  sample=1)
+        # (rows, slots) and (rows, buckets) over the sample only.
+        slot = ((h[:, None] == hh.keys[None, :]) & hh.slot_valid[None, :]
+                & valid[:, None])
+        heavy = jnp.any(slot, axis=1)
+        dest = (h % jnp.uint64(n_buckets)).astype(jnp.int32)
+        to = dest[:, None] == jnp.arange(n_buckets, dtype=jnp.int32)
+        fullest = pmax(jnp.max(jnp.sum(to & valid[:, None], axis=0)))
+        rest = pmax(jnp.max(jnp.sum(to & (valid & ~heavy)[:, None],
+                                    axis=0)))
+        # The heavy keys overflow the shuffle, and diverting them
+        # relieves it (sampled rows against the capacity, scaled).
+        cap = jnp.int64(bucket_capacity) * sample_rows
+        on = ((pmax(jnp.sum(hh.slot_valid)) > 0)
+              & (fullest * p_rows > cap) & (rest * p_rows <= cap))
+
+        def count_build(_):
+            bh = hash_columns([build.columns[k] for k in keys_eff])
+            hit = ((bh[:, None] == hh.keys[None, :])
+                   & hh.slot_valid[None, :] & build.valid[:, None])
+            return jnp.sum(hit, axis=0, dtype=jnp.int64)
+
+        def no_count(_):
+            return _pvary(jnp.zeros((hh_slots,), jnp.int64), axis_name)
+
+        # Only a resolution that turns on reads the whole build side.
+        local_b = lax.cond(on, count_build, no_count, None)
+        per_key = lax.psum(local_b, axis_name)
+        out = jnp.sum(jnp.where(slot, per_key[None, :], 0))
+        return jnp.stack([
+            on.astype(jnp.int64), fullest, pmax(jnp.sum(heavy)),
+            pmax(out), pmax(jnp.sum(local_b)), jnp.max(per_key),
+        ])
+
+    return jax.jit(jax.shard_map(step, mesh=mesh,
+                                 in_specs=P(axis_name), out_specs=P()))
+
+
+def resolve(comm: Communicator, build: Table, probe: Table, key, *,
+            n_buckets: int, bucket_capacity: int, hh_slots: int,
+            hh_build_floor: int) -> Resolution:
+    """Decide the skew path from the input, for row-sharded global
+    tables over ``comm``'s mesh (runs the detection program and reads
+    its result). ``n_buckets`` and ``bucket_capacity`` are the padded
+    shuffle's buckets and per-bucket rows; ``hh_build_floor`` is the
+    smallest HH build block.
+
+    On, the options set ``skew_threshold`` (:data:`RESOLVE_THRESHOLD`)
+    and the three HH blocks, each from what was measured with
+    :data:`RESOLVE_SLACK` of headroom, and rounded up so that tables of
+    one shape and distribution resolve alike: the probe and output
+    blocks to eighths of a rank's probe rows (the probe block at most
+    all of them; the output block at most the probe block times the
+    most build rows of one heavy key), the build block to a power of
+    two. An estimate that falls short costs an overflow flag (the
+    ladder's to relieve), never a wrong row."""
+    import numpy as np
+
+    n = comm.n_ranks
+    p_rows = probe.capacity // n
+    m = min(RESOLVE_SAMPLE_ROWS, p_rows)
+    keys = (key,) if isinstance(key, str) else tuple(key)
+    threshold = int(RESOLVE_THRESHOLD * p_rows) * m // p_rows
+    fn = _detection_program(comm.mesh, comm.axis_name, keys, n_buckets,
+                            bucket_capacity, m, hh_slots, threshold)
+    on, fullest, hh_probe, hh_out, hh_build, per_key = (
+        int(v) for v in np.asarray(fn(build, probe)))
+
+    def rows(sampled):
+        return -(-sampled * p_rows // m)
+
+    unit = -(-p_rows // RESOLVE_GRID)
+    unit += -unit % 8
+
+    def grid(x):
+        return max(1, -(-math.ceil(x) // unit)) * unit
+
+    options = {}
+    if on:
+        probe_cap = min(grid(rows(hh_probe) * RESOLVE_SLACK), p_rows)
+        options = {
+            "skew_threshold": RESOLVE_THRESHOLD,
+            "hh_probe_capacity": probe_cap,
+            "hh_out_capacity": min(grid(rows(hh_out) * RESOLVE_SLACK),
+                                   grid(probe_cap * per_key)),
+            "hh_build_capacity": max(
+                hh_build_floor,
+                1 << max(0, math.ceil(hh_build * RESOLVE_SLACK) - 1
+                         ).bit_length()),
+        }
+    return Resolution(bool(on), bucket_capacity, rows(fullest),
+                      rows(hh_probe), options)

@@ -59,7 +59,7 @@ __all__ = [
     "Metrics", "MetricsTape", "TelemetrySink",
     "configure", "configure_from_args", "counter_add",
     "current_trace", "emit_metrics", "enabled", "event", "finalize",
-    "maybe_start_xla_trace", "request_scope", "session", "sink",
+    "maybe_start_xla_trace", "note", "request_scope", "session", "sink",
     "span", "span_complete", "stage_profile", "summary",
 ]
 
@@ -180,9 +180,11 @@ class _ProfilerSpan:
 
     def __enter__(self):
         self._annotation.__enter__()
+        _spans.annotations().append(self._annotation)
         return None
 
     def __exit__(self, *exc):
+        _spans.annotations().pop()
         return self._annotation.__exit__(*exc)
 
 
@@ -200,6 +202,19 @@ def span(name: str, **payload):
     if _active is None:
         return _ProfilerSpan(name, payload)
     return _spans.span_scope(_active, name, payload or None)
+
+
+def note(**kv) -> None:
+    """Attach arguments to the innermost open :func:`span` of this
+    thread, for what becomes known inside it: its ``TraceAnnotation``
+    carries them into a running profiler's trace, and with a session
+    its sink event carries them too. No-op outside any span."""
+    anns = _spans.annotations()
+    if anns:
+        anns[-1].set_metadata(**kv)
+    stack = _spans._stack()
+    if _active is not None and stack:
+        stack[-1].note(**kv)
 
 
 def span_complete(name: str, t0_perf: float, dur_s: float, **payload) -> None:

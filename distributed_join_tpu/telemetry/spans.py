@@ -56,6 +56,15 @@ def fetch_one_scalar(x):
 _tls = threading.local()
 
 
+def annotations() -> list:
+    """This thread's open spans' ``TraceAnnotation``s, innermost
+    last (what ``telemetry.note`` attaches arguments to)."""
+    s = getattr(_tls, "annotations", None)
+    if s is None:
+        s = _tls.annotations = []
+    return s
+
+
 def _stack() -> list:
     s = getattr(_tls, "stack", None)
     if s is None:
@@ -96,7 +105,8 @@ def span_scope(sink, name: str, payload: Optional[dict] = None):
     stack.append(sp)
     err = None
     try:
-        with jax.profiler.TraceAnnotation(name, **sp.payload):
+        with jax.profiler.TraceAnnotation(name, **sp.payload) as ann:
+            annotations().append(ann)
             sp.t0 = time.perf_counter()
             try:
                 yield sp
@@ -105,6 +115,8 @@ def span_scope(sink, name: str, payload: Optional[dict] = None):
             except BaseException as exc:
                 err = exc
                 raise
+            finally:
+                annotations().pop()
     finally:
         dur = time.perf_counter() - sp.t0
         stack.pop()

@@ -153,10 +153,10 @@ KERNELS = {"join_scan_r", "join_scan_f", "compact_planes", "expand",
 INSTRUCTION = re.compile(r"\s*(?:ROOT )?%(\S+) = (.*?) ([a-z][a-z0-9\-]*)\(")
 
 
-def _join_step_hlo(topo, monkeypatch, scopes=True):
+def _join_step_hlo(topo, monkeypatch, scopes=True, **opts):
     """The optimised HLO of the one-chip join step (the Pallas kernel
     pipeline, as on the chip); ``scopes=False`` traces it with every
-    ``jax.named_scope`` turned into a no-op."""
+    ``jax.named_scope`` turned into a no-op. ``opts`` go to the step."""
     import contextlib
 
     import numpy as np
@@ -176,7 +176,7 @@ def _join_step_hlo(topo, monkeypatch, scopes=True):
     comm = TpuCommunicator(mesh=Mesh(np.array(topo.devices[:1]),
                                      ("ranks",)))
     build, probe = abstract_join_tables(comm, JOIN_ROWS)
-    fn = make_distributed_join(comm, key="key")
+    fn = make_distributed_join(comm, key="key", **opts)
     return fn.lower(build, probe).compile().as_text()
 
 
@@ -211,6 +211,26 @@ def test_join_step_names_its_stages_and_kernels(join_hlo):
             kernels.add(re.sub(r"\.\d+$", "", name))
     assert kernels == KERNELS
     assert {"sort", "scan", "compact", "expand"} <= seen
+
+
+def _kernels(text):
+    return {re.sub(r"\.\d+$", "", name)
+            for name, _, _, line in _instructions(text)
+            if 'custom_call_target="tpu_custom_call"' in line}
+
+
+def test_skew_step_names_the_heavy_hitter_kernels(topo, monkeypatch):
+    """The skew step's heavy-key join runs its kernels under an ``hh_``
+    prefix and compacts the heavy rows with ``hh_extract``; the normal
+    path keeps its names, which ``local_join.*_kernel_ms_per_call``
+    read. Blocks smaller than half the rows, so the compaction kernel
+    runs."""
+    text = _join_step_hlo(topo, monkeypatch, skew_threshold=0.01,
+                          hh_probe_capacity=JOIN_ROWS // 4,
+                          hh_build_capacity=JOIN_ROWS // 8,
+                          hh_out_capacity=JOIN_ROWS // 2)
+    hh = {"hh_" + k for k in KERNELS} | {"hh_extract"}
+    assert _kernels(text) == KERNELS | hh
 
 
 def test_scopes_leave_the_instructions_alone(topo, join_hlo, monkeypatch):

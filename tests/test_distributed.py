@@ -173,6 +173,28 @@ def test_distributed_join_overflow_reported(comm8):
          "probe_payload": jnp.arange(1024, dtype=jnp.int64)}
     )
     res = distributed_inner_join(
-        build, probe, comm8, shuffle_capacity_factor=1.0
+        build, probe, comm8, shuffle_capacity_factor=1.0,
+        skew_threshold=0,  # the plain path: no heavy-hitter sidecar
     )
     assert bool(res.overflow)
+
+
+def test_distributed_join_hot_key_resolves_the_skew_path(comm8):
+    # the same one-key probe with no skew knob: the entry sees the hot
+    # key, keeps its rows local and the padded shuffle fits
+    build = Table.from_dense(
+        {"key": jnp.arange(64, dtype=jnp.int64),
+         "build_payload": jnp.arange(64, dtype=jnp.int64)}
+    )
+    probe = Table.from_dense(
+        {"key": jnp.zeros(1024, dtype=jnp.int64),
+         "probe_payload": jnp.arange(1024, dtype=jnp.int64)}
+    )
+    res = distributed_inner_join(
+        build, probe, comm8, shuffle_capacity_factor=1.0
+    )
+    assert not bool(res.overflow)
+    assert int(res.total) == 1024
+    got = res.table.to_pandas()
+    assert (got["key"] == 0).all() and (got["build_payload"] == 0).all()
+    assert sorted(got["probe_payload"]) == list(range(1024))

@@ -118,7 +118,7 @@ def test_zipf_skew_relieves_shuffle_padding():
     )
     naive = dj.distributed_inner_join(
         build, probe, comm, shuffle_capacity_factor=1.3,
-        out_capacity_factor=2.0,
+        out_capacity_factor=2.0, skew_threshold=0,  # the plain path
     )
     assert bool(naive.overflow)  # Zipf breaks naive padding
 
@@ -144,6 +144,7 @@ def test_auto_retry_recovers_from_overflow():
     res = dj.distributed_inner_join(
         build, probe, comm, shuffle_capacity_factor=1.1,
         out_capacity_factor=1.2, auto_retry=4,
+        skew_threshold=0,  # the plain path, so the ladder relieves it
     )
     assert not bool(res.overflow)
     assert int(res.total) == _oracle(build, probe)
@@ -212,3 +213,193 @@ def test_sampled_detection_sees_periodic_heavy_key():
     # and the scaled count estimate is in the right ballpark (half n)
     cnt = int(np.asarray(hh.counts)[np.asarray(hh.keys) == 7][0])
     assert n // 4 < cnt < n, cnt
+
+
+# -- the entry resolves the skew path from the input -------------------
+
+
+def _zipf_tables(seed, rows_per_rank, n_ranks=4):
+    """The benchmark's Zipf alpha=1.5 foreign-key tables (unique build
+    keys over [0, rows), probe keys Zipf over the same keys)."""
+    from joinbench import run
+    from joinbench.data import zipf
+
+    rows = rows_per_rank * n_ranks
+    cfg = {"build_rows": rows, "probe_rows": rows, "rand_max": rows,
+           "zipf_alpha": 1.5, "key_dtype": "int64",
+           "payload_dtype": "int64"}
+    kd = run.seed_key_data(seed, 0)
+    t = jax.jit(lambda kd: zipf.tables(cfg, jax.random.wrap_key_data(kd))
+                )(kd)
+    return tuple(Table(dict(t[s]["columns"]), t[s]["valid"])
+                 for s in ("build", "probe"))
+
+
+def _uniform_tables(rows=16384):
+    from distributed_join_tpu.utils.generators import (
+        generate_build_probe_tables,
+    )
+
+    return generate_build_probe_tables(
+        seed=7, build_nrows=rows, probe_nrows=rows, selectivity=0.3)
+
+
+def _resolved(comm, build, probe, **opts):
+    """The options the entry hands on after its skew resolution."""
+    from distributed_join_tpu.parallel.distributed_join import (
+        _resolve_skew,
+    )
+
+    build, probe = comm.device_put_sharded((build, probe))
+    _resolve_skew(comm, build, probe, "key", opts)
+    return opts
+
+
+def _digest_matches_reference(res, build, probe):
+    from joinbench import oracle
+
+    names = ("key", ["build_payload"], ["probe_payload"])
+    got = oracle.to_ints(oracle.digest(
+        {k: np.asarray(v) for k, v in res.table.columns.items()},
+        np.asarray(res.table.valid), *names, np))
+    side = [{k: np.asarray(v)[np.asarray(t.valid)]
+             for k, v in t.columns.items()} for t in (build, probe)]
+    want = oracle.reference(*side, *names)
+    return oracle.mismatches(got, want)
+
+
+def test_entry_resolves_skew_on_for_zipf_keys():
+    """No knob: Zipf alpha=1.5 probe keys against unique build keys
+    would overflow the padded shuffle; the entry turns the skew path
+    on, sized from the data, and the answer is the reference's."""
+    comm = dj.make_communicator("tpu", n_ranks=4)
+    build, probe = _zipf_tables(11, 10_000)
+    plain = dj.distributed_inner_join(build, probe, comm, skew_threshold=0)
+    assert bool(plain.overflow)
+    res = dj.distributed_inner_join(build, probe, comm)
+    assert not bool(res.overflow)
+    assert _digest_matches_reference(res, build, probe) == []
+    (attempt,) = res.retry_report.attempts
+    # About 88% of the probe rows are heavy: the probe block is the
+    # rank's rows, and each heavy probe row matches one build row.
+    assert attempt.hh_probe_capacity == 10_000
+    assert 10_000 <= attempt.hh_out_capacity < 11_000
+    assert attempt.hh_build_capacity == 2048
+
+
+@pytest.mark.parametrize("rows_per_rank", [10_000, 50_000],
+                         ids=["whole", "sampled"])
+def test_two_seeds_resolve_to_the_same_sizing(rows_per_rank):
+    """Tables of one shape and distribution resolve alike, sampled or
+    read whole, so one warmed program serves both."""
+    comm = dj.make_communicator("tpu", n_ranks=4)
+    a = _resolved(comm, *_zipf_tables(3, rows_per_rank))
+    b = _resolved(comm, *_zipf_tables(2**40 + 5, rows_per_rank))
+    assert a == b and a["skew_threshold"] > 0
+
+
+def test_uniform_keys_resolve_off_to_the_plain_program():
+    """Uniform keys resolve off, and what runs is the program of the
+    explicit off setting: the same lowering, one program-cache entry."""
+    from distributed_join_tpu.parallel.distributed_join import (
+        make_distributed_join,
+        resolve_join_ladder,
+    )
+    from distributed_join_tpu.service.programs import JoinProgramCache
+
+    comm = dj.make_communicator("tpu", n_ranks=4)
+    build, probe = _uniform_tables()
+    auto = _resolved(comm, build, probe)
+    off = _resolved(comm, build, probe, skew_threshold=0)
+    assert auto == off == {}
+
+    def lowered(opts):
+        b, p = comm.device_put_sharded((build, probe))
+        ladder = resolve_join_ladder(b, p, comm.n_ranks, dict(opts))
+        return make_distributed_join(comm, key="key", **ladder.sizing(),
+                                     **opts).lower(b, p).as_text()
+
+    assert lowered(auto) == lowered(off)
+    cache = JoinProgramCache(comm)
+    for opts in ({}, {"skew_threshold": 0}):
+        dj.distributed_inner_join(build, probe, comm, program_cache=cache,
+                                  **opts)
+    assert cache.traces == 1
+
+
+@pytest.mark.parametrize("make_comm", [
+    lambda: dj.make_communicator("tpu", n_ranks=1),
+    lambda: dj.make_communicator("local"),
+], ids=["tpu", "local"])
+def test_one_rank_runs_no_prepass(make_comm, monkeypatch):
+    from distributed_join_tpu.parallel import skew
+
+    def no_prepass(*a, **k):
+        raise AssertionError("the detection program ran")
+
+    monkeypatch.setattr(skew, "resolve", no_prepass)
+    comm = make_comm()
+    build, probe = _zipf_tables(5, 16_000, n_ranks=1)
+    res = dj.distributed_inner_join(build, probe, comm,
+                                    out_capacity_factor=1.5)
+    assert not bool(res.overflow)
+    assert _digest_matches_reference(res, build, probe) == []
+
+
+def _agg_spec():
+    from distributed_join_tpu.ops.aggregate import AggregateSpec
+
+    return AggregateSpec.of("key", [("count", None, "n")])
+
+
+@pytest.mark.parametrize("opts", [
+    {"join_type": "left"},
+    {"aggregate": "count"},
+    {"sort_mode": "segmented"},
+], ids=["left", "aggregate", "segmented"])
+def test_unsupported_shapes_skip_the_prepass(opts, monkeypatch):
+    """Outer joins, aggregates and the segmented sort on Zipf keys run
+    as before: no detection program, no new refusal."""
+    from distributed_join_tpu.parallel import skew
+
+    def no_prepass(*a, **k):
+        raise AssertionError("the detection program ran")
+
+    monkeypatch.setattr(skew, "resolve", no_prepass)
+    if "aggregate" in opts:
+        opts = {"aggregate": _agg_spec()}
+    comm = dj.make_communicator("tpu", n_ranks=4)
+    build, probe = _zipf_tables(9, 4_000)
+    dj.distributed_inner_join(build, probe, comm, auto_retry=3,
+                              out_capacity_factor=2.0, **opts)
+
+
+@pytest.mark.filterwarnings("ignore:builtin type event_stats")
+def test_skew_resolve_span_carries_what_it_found(tmp_path):
+    """The host reads the detection program's result under
+    ``entry.skew_resolve``, whose arguments say whether the path turned
+    on, the heavy probe rows and the blocks' sizes."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    comm = dj.make_communicator("tpu", n_ranks=4)
+    zipf_tables = _zipf_tables(11, 10_000)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for build, probe in (zipf_tables, _uniform_tables()):
+            dj.distributed_inner_join(build, probe, comm)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/**/*.xplane.pb", recursive=True)
+    args = [dict(e.stats) for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU" for line in plane.lines
+            for e in line.events if e.name == "entry.skew_resolve"]
+    assert [a["on"] for a in args] == [1, 0]
+    on, off = args
+    assert 8_000 < on["hh_probe_rows"] <= 10_000
+    assert on["hh_probe_capacity"] == 10_000
+    assert on["hh_build_capacity"] == 2048
+    assert on["fullest_bucket"] > on["bucket_capacity"]
+    assert off["fullest_bucket"] <= off["bucket_capacity"]
+    assert off["hh_probe_capacity"] == off["hh_out_capacity"] == 0
