@@ -76,6 +76,20 @@ f32-HIGHEST's ~6 emulation passes), and its record window is
 non-build kernel's 1-D int32 S array, whose DMA tiling forces
 1024-aligned offsets and hence 2B windows).
 
+Live blocks: the output's capacity is static (the join sizes it as a
+multiple of the probe rows), but its rows fill it from slot 0 up to
+the match total, which the program holds on the device. Both kernels
+take that count (``live``) as a scalar-prefetch operand, beside the
+window offsets: a block that starts at or past it skips its whole body
+(no DMA, no compare-and-matmul loop, no store), and the output
+BlockSpec parks every later step of the tile on the last live block,
+so consecutive dead steps share one resident buffer and write nothing
+back. A dead step costs its grid-step overhead and an SMEM read. The
+slots past ``live`` are left undefined rather than filled: the caller
+already masks every slot at or past the match total (``valid = j <
+total``), so filling them would only spend the time this saves, and a
+full output runs the whole grid as before.
+
 Everything the kernels touch moves sequentially (record windows,
 build windows, output blocks); the join's output path has no
 per-element random access left. ``expand_gather_reference`` is the XLA
@@ -260,11 +274,11 @@ def _merge_rows8(rows_f32: jax.Array, k: int):
     return out
 
 
-def _expand_kernel(r0b_ref, ib_ref, s_hbm, v_hbm, out_ref, s_vmem,
-                   v_vmem, sem_s, sem_v, *, block: int, chunk: int,
-                   ck: int, srow: int):
-    """Per-output-block body, record expansion only (the build path
-    runs _expand_kernel_b8); see module docstring for the scheme.
+def _expand_block(r0b_ref, ib_ref, s_hbm, v_hbm, out_ref, s_vmem,
+                  v_vmem, sem_s, sem_v, *, i, block: int, chunk: int,
+                  ck: int, srow: int):
+    """Output block ``i``'s body, record expansion only (the build
+    path runs _expand_block_b8); see module docstring for the scheme.
 
     Mosaic constraints shaping this code:
     - dynamic DMA offsets must be PROVABLY divisible by the tiling
@@ -281,7 +295,6 @@ def _expand_kernel(r0b_ref, ib_ref, s_hbm, v_hbm, out_ref, s_vmem,
     from jax.experimental.pallas import tpu as pltpu
 
     b = block
-    i = pl.program_id(0)
     w = r0b_ref[i] * b  # provably block-aligned
     dma_s = pltpu.make_async_copy(s_hbm.at[pl.ds(w, 2 * b)], s_vmem, sem_s)
     dma_v = pltpu.make_async_copy(
@@ -331,8 +344,8 @@ def _expand_kernel(r0b_ref, ib_ref, s_hbm, v_hbm, out_ref, s_vmem,
     out_ref[...] = acc
 
 
-def _expand_kernel_b8(*refs, block: int, chunk: int, ck8: int,
-                      ckb8: int, wr: int, w1w: int, w2w: int):
+def _expand_block_b8(*refs, i, block: int, chunk: int, ck8: int,
+                     ckb8: int, wr: int, w1w: int, w2w: int):
     """Build-mode kernel, v3: 8-bit bf16 chunk rows for every value
     matmul (one MXU pass instead of ~6 f32-HIGHEST emulation passes),
     record windows 128-aligned (width b+chunk-slack instead of 2b — the
@@ -348,7 +361,6 @@ def _expand_kernel_b8(*refs, block: int, chunk: int, ck8: int,
      out_ref, v8_vmem, aux_vmem, b1_vmem, b2_vmem, sem_v, sem_a,
      sem_b1, sem_b2) = refs
     b = block
-    i = pl.program_id(0)
     wro = r0a_ref[i] * 128  # 128-aligned record-window offset
     dma_v = pltpu.make_async_copy(
         v8_hbm.at[:, pl.ds(wro, wr)], v8_vmem, sem_v
@@ -472,6 +484,36 @@ def _expand_kernel_b8(*refs, block: int, chunk: int, ck8: int,
     out_ref[ck8 : ck8 + ckb8, :] = accb
 
 
+def _expand_kernel(r0b_ref, ib_ref, bound_ref, *refs, **kw):
+    """The record-only kernel: _expand_block for a block that starts
+    below the live slot count ``bound_ref[0]``, nothing otherwise
+    (module docstring, "Live blocks")."""
+    import jax.experimental.pallas as pl
+
+    # program_id outside the branch: the interpreter (CPU tests)
+    # resolves it only at the kernel's top level.
+    i = pl.program_id(0)
+
+    @pl.when(ib_ref[i] < bound_ref[0])
+    def _():
+        _expand_block(r0b_ref, ib_ref, *refs, i=i, **kw)
+
+
+def _expand_kernel_b8(r0a_ref, w1a_ref, w2a_ref, ib_ref, bound_ref,
+                      *refs, **kw):
+    """The build-mode kernel: _expand_block_b8 for a block that starts
+    below the live slot count ``bound_ref[0]``, nothing otherwise
+    (module docstring, "Live blocks")."""
+    import jax.experimental.pallas as pl
+
+    i = pl.program_id(0)
+
+    @pl.when(ib_ref[i] < bound_ref[0])
+    def _():
+        _expand_block_b8(r0a_ref, w1a_ref, w2a_ref, ib_ref, *refs, i=i,
+                         **kw)
+
+
 # Per-tile budget for the build-mode kernel's f32 chunk-row output
 # (~32 B per u64 lane per output row — 4x the value width). One
 # monolithic buffer OOM'd HBM at a 60M-row output capacity
@@ -480,13 +522,14 @@ def _expand_kernel_b8(*refs, block: int, chunk: int, ck8: int,
 _FUSED_TILE_BYTES = 2 << 30
 
 
-def _tiled_output_launch(n_blocks, block, tile_bytes, launch, merge):
+def _tiled_output_launch(n_blocks, block, tile_bytes, live, launch,
+                         merge):
     """Shared output-tiling driver for both expand wrappers: run
-    ``launch(q, qb, ib_arr) -> raw f32 (rows, qb*block)`` once per
-    HBM-budget tile of output blocks, ``merge(out) -> pytree of 1-D
-    arrays`` per tile, and concatenate the merged pieces.
+    ``launch(q, qb, ib_arr, bound) -> raw f32 (rows, qb*block)`` once
+    per HBM-budget tile of output blocks, ``merge(out) -> pytree of
+    1-D arrays`` per tile, and concatenate the merged pieces.
 
-    Two invariants live ONLY here (review r5 — they were hand-copied
+    Three invariants live ONLY here (review r5 — they were hand-copied
     in both wrappers before):
     - tile sizing: ceil-divide ``tile_bytes`` into the
       ``_FUSED_TILE_BYTES`` budget, never more tiles than blocks;
@@ -494,7 +537,13 @@ def _tiled_output_launch(n_blocks, block, tile_bytes, launch, merge):
       carry a ``dep`` tied to the previous tile's output through an
       optimization_barrier — a plain ``x * 0`` would be algebraically
       folded to a constant, severing the ordering that lets buffer
-      assignment reuse the f32 space across tiles.
+      assignment reuse the f32 space across tiles;
+    - the live bound: ``bound`` is ``[live, last]`` (int32), the live
+      slot count and the tile-local index of the tile's last block
+      that starts below it (0 for a tile with none). The kernels skip
+      every block starting at or past ``live`` and park their output
+      window on block ``last`` (_out_spec), so no dead block is
+      computed or written back.
     """
     n_tiles = min(max(1, -(-tile_bytes // _FUSED_TILE_BYTES)), n_blocks)
     tile_blocks = -(-n_blocks // n_tiles)
@@ -505,7 +554,11 @@ def _tiled_output_launch(n_blocks, block, tile_bytes, launch, merge):
         ib_arr = (
             jnp.arange(qb, dtype=jnp.int32) + jnp.int32(q)
         ) * block + dep
-        out = launch(q, qb, ib_arr)
+        # floor((live - 1) / block) is the global index of the last
+        # live block (-1 when live == 0); no ceil, so live near 2^31
+        # cannot overflow.
+        last = jnp.clip((live - 1) // block - q, 0, qb - 1)
+        out = launch(q, qb, ib_arr, jnp.stack([live, last]))
         pieces.append(merge(out))
         dep = lax.optimization_barrier(
             (jnp.int32(0), out[0, 0])
@@ -517,7 +570,43 @@ def _tiled_output_launch(n_blocks, block, tile_bytes, launch, merge):
     )
 
 
-def _expand_gather_b8(S, cols, out_capacity, block, interpret, lo,
+def _out_spec(rows: int, block: int):
+    """Output BlockSpec of both kernels under their scalar-prefetch
+    grid: step i writes output block i, except that steps past the
+    tile's last live block stay on it (``bound[1]``, the last
+    scalar-prefetch operand). Consecutive steps on one block keep one
+    resident VMEM buffer, so dead steps cause no writeback; the dead
+    blocks' HBM is never written."""
+    import jax.experimental.pallas as pl
+
+    return pl.BlockSpec(
+        (rows, block),
+        lambda i, *scalars: (0, jnp.minimum(i, scalars[-1][1])),
+    )
+
+
+def _live_slots(live, out_capacity: int) -> jax.Array:
+    """``live`` (any integer dtype, traced) clipped to the output's
+    slot range, as the kernels' int32 bound."""
+    return jnp.clip(live, 0, out_capacity).astype(jnp.int32)
+
+
+def expand_blocks(live, out_capacity: int, block: int | None = None):
+    """``(live_blocks, grid_blocks)`` of the expand kernels' grid for
+    ``live`` live slots in an ``out_capacity``-slot output: the blocks
+    that start below the live count (traced int32) and the static grid
+    (``ceil(out_capacity / block)``). Their ratio is the share of the
+    grid the kernels still compute; the metrics tape records both
+    (parallel/distributed_join.py)."""
+    if block is None:
+        block = _default_block()
+    n = _live_slots(live, out_capacity)
+    # (n - 1) // block + 1 is ceil(n / block) for n >= 0 (floor
+    # division maps n == 0 to 0) without overflowing near 2^31.
+    return (n - 1) // block + 1, _round_up(out_capacity, block) // block
+
+
+def _expand_gather_b8(S, cols, out_capacity, live, block, interpret, lo,
                       build_cols, window=None, name_prefix=""):
     """v3 build-mode wrapper; see _expand_kernel_b8."""
     import jax.experimental.pallas as pl
@@ -640,7 +729,7 @@ def _expand_gather_b8(S, cols, out_capacity, block, interpret, lo,
     # so the SAME compiled kernel covers any output range — run it
     # per tile and concatenate the merged u64 pieces
     # (_tiled_output_launch owns the tile sizing + serialization).
-    def _launch(q, qb, ib_arr):
+    def _launch(q, qb, ib_arr, bound):
         sl = slice(q, q + qb)
         out_shape = (
             jax.ShapeDtypeStruct((ck8 + ckb8, qb * block),
@@ -659,34 +748,33 @@ def _expand_gather_b8(S, cols, out_capacity, block, interpret, lo,
                     ck8=ck8, ckb8=ckb8, wr=wr, w1w=w1w, w2w=w2w,
                 ),
                 name=f"{name_prefix}expand_b8",
-                grid=(qb,),
-                in_specs=[
-                    pl.BlockSpec(memory_space=pltpu.SMEM),
-                    pl.BlockSpec(memory_space=pltpu.SMEM),
-                    pl.BlockSpec(memory_space=pltpu.SMEM),
-                    pl.BlockSpec(memory_space=pltpu.SMEM),
-                    pl.BlockSpec(memory_space=pl.ANY),
-                    pl.BlockSpec(memory_space=pl.ANY),
-                    pl.BlockSpec(memory_space=pl.ANY),
-                ],
-                out_specs=pl.BlockSpec((ck8 + ckb8, block),
-                                       lambda i: (0, i)),
-                scratch_shapes=[
-                    pltpu.VMEM((ck8, wr), jnp.bfloat16),
-                    pltpu.VMEM((8, wr), jnp.float32),
-                    pltpu.VMEM((ckb8, w1w), jnp.bfloat16),
-                    pltpu.VMEM((ckb8, w2w), jnp.bfloat16),
-                    pltpu.SemaphoreType.DMA(()),
-                    pltpu.SemaphoreType.DMA(()),
-                    pltpu.SemaphoreType.DMA(()),
-                    pltpu.SemaphoreType.DMA(()),
-                ],
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=5,
+                    grid=(qb,),
+                    in_specs=[
+                        pl.BlockSpec(memory_space=pl.ANY),
+                        pl.BlockSpec(memory_space=pl.ANY),
+                        pl.BlockSpec(memory_space=pl.ANY),
+                    ],
+                    out_specs=_out_spec(ck8 + ckb8, block),
+                    scratch_shapes=[
+                        pltpu.VMEM((ck8, wr), jnp.bfloat16),
+                        pltpu.VMEM((8, wr), jnp.float32),
+                        pltpu.VMEM((ckb8, w1w), jnp.bfloat16),
+                        pltpu.VMEM((ckb8, w2w), jnp.bfloat16),
+                        pltpu.SemaphoreType.DMA(()),
+                        pltpu.SemaphoreType.DMA(()),
+                        pltpu.SemaphoreType.DMA(()),
+                        pltpu.SemaphoreType.DMA(()),
+                    ],
+                ),
                 out_shape=out_shape,
                 interpret=interpret,
-            )(r0a[sl], w1a[sl], w2a[sl], ib_arr, v8T, auxT, bv8T)
+            )(r0a[sl], w1a[sl], w2a[sl], ib_arr, bound, v8T, auxT, bv8T)
 
     rec_full, build_full = _tiled_output_launch(
-        out_pad // block, block, (ck8 + ckb8) * 4 * out_pad, _launch,
+        out_pad // block, block, (ck8 + ckb8) * 4 * out_pad,
+        _live_slots(live, out_capacity), _launch,
         lambda out: (_merge_rows8(out, k), _merge_rows8(out[ck8:], kb)),
     )
     rec_outs = [c[:out_capacity] for c in rec_full]
@@ -699,7 +787,8 @@ def _expand_gather_b8(S, cols, out_capacity, block, interpret, lo,
 
 
 def expand_gather(S: jax.Array, cols: Sequence[jax.Array],
-                  out_capacity: int, block: int | None = None,
+                  out_capacity: int, live,
+                  block: int | None = None,
                   interpret: bool = False,
                   lo: Optional[jax.Array] = None,
                   build_cols: Optional[Sequence[jax.Array]] = None,
@@ -727,8 +816,14 @@ def expand_gather(S: jax.Array, cols: Sequence[jax.Array],
     BUILD path start_b and rank are ZERO PLACEHOLDERS: both quantities
     are consumed inside the kernel and exist in the return value only
     so the caller's lax.cond branches (kernel vs XLA-gather fallback)
-    have matching pytrees. Values at slots >= the true total are
-    garbage (masked by the caller).
+    have matching pytrees.
+
+    ``live`` (traced integer scalar) is the number of leading output
+    slots that hold rows — the join's match total; values outside
+    ``[0, out_capacity]`` are clipped. Only blocks that start below it
+    are computed: values at slots >= ``live`` are undefined (not
+    computed, their memory never written), and the caller masks them
+    (module docstring, "Live blocks").
 
     ``block`` must be a multiple of 1024 on real TPUs (the 1-D int32
     DMA tiling; the kernel proves window offsets divisible by it);
@@ -753,8 +848,8 @@ def expand_gather(S: jax.Array, cols: Sequence[jax.Array],
         # ``window`` (build path only) widens the two build windows
         # independently of the block (_window_widths).
         return _expand_gather_b8(
-            S, cols, out_capacity, block, interpret, lo, build_cols,
-            window=window, name_prefix=name_prefix,
+            S, cols, out_capacity, live, block, interpret, lo,
+            build_cols, window=window, name_prefix=name_prefix,
         )
     k = len(cols)
     m = S.shape[0]
@@ -815,7 +910,7 @@ def expand_gather(S: jax.Array, cols: Sequence[jax.Array],
     # once — the exact monolithic footprint tiling exists to avoid.
     chunk = _default_chunk(block)
 
-    def _launch(q, qb, ib_arr):
+    def _launch(q, qb, ib_arr, bound):
         out_shape = (
             jax.ShapeDtypeStruct((ck, qb * block), jnp.float32, vma=vma)
             if vma is not None
@@ -824,9 +919,8 @@ def expand_gather(S: jax.Array, cols: Sequence[jax.Array],
         # Global x64 breaks Mosaic legalization ("failed to legalize
         # func.return" — i64 index plumbing); every type here is
         # explicit i32/f32, so scope x64 off around the kernel. The
-        # offsets ride a plain SMEM input + manual DMA because
-        # PrefetchScalarGridSpec also fails to legalize with this
-        # toolchain.
+        # windows' offsets are scalar-prefetched beside the block
+        # starts and the live bound; the windows move by manual DMA.
         with jax.enable_x64(False):
             return pl.pallas_call(
                 functools.partial(
@@ -834,23 +928,24 @@ def expand_gather(S: jax.Array, cols: Sequence[jax.Array],
                     ck=ck, srow=srow,
                 ),
                 name=f"{name_prefix}expand",
-                grid=(qb,),
-                in_specs=[
-                    pl.BlockSpec(memory_space=pltpu.SMEM),
-                    pl.BlockSpec(memory_space=pltpu.SMEM),
-                    pl.BlockSpec(memory_space=pl.ANY),
-                    pl.BlockSpec(memory_space=pl.ANY),
-                ],
-                out_specs=pl.BlockSpec((ck, block), lambda i: (0, i)),
-                scratch_shapes=[
-                    pltpu.VMEM((2 * block,), jnp.int32),
-                    pltpu.VMEM((ck, 2 * block), jnp.float32),
-                    pltpu.SemaphoreType.DMA(()),
-                    pltpu.SemaphoreType.DMA(()),
-                ],
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=3,
+                    grid=(qb,),
+                    in_specs=[
+                        pl.BlockSpec(memory_space=pl.ANY),
+                        pl.BlockSpec(memory_space=pl.ANY),
+                    ],
+                    out_specs=_out_spec(ck, block),
+                    scratch_shapes=[
+                        pltpu.VMEM((2 * block,), jnp.int32),
+                        pltpu.VMEM((ck, 2 * block), jnp.float32),
+                        pltpu.SemaphoreType.DMA(()),
+                        pltpu.SemaphoreType.DMA(()),
+                    ],
+                ),
                 out_shape=out_shape,
                 interpret=interpret,
-            )(r0b[q : q + qb], ib_arr, S, vT)
+            )(r0b[q : q + qb], ib_arr, bound, S, vT)
 
     def _merge(out):
         if s_u64_lane:
@@ -862,7 +957,8 @@ def expand_gather(S: jax.Array, cols: Sequence[jax.Array],
         return _merge_rows(out, k), sb
 
     rec_full, sb_full = _tiled_output_launch(
-        out_pad // block, block, ck * 4 * out_pad, _launch, _merge
+        out_pad // block, block, ck * 4 * out_pad,
+        _live_slots(live, out_capacity), _launch, _merge,
     )
     rec_outs = [c[:out_capacity] for c in rec_full]
     start_b = sb_full[:out_capacity]

@@ -185,7 +185,7 @@ def _from_u64_lane(c64: jax.Array, dt):
     raise TypeError(dt)
 
 
-def _expand_records(S, recs: dict, out_capacity: int, j, cfg,
+def _expand_records(S, recs: dict, out_capacity: int, j, total, cfg,
                     kernel_prefix: str = ""):
     """Broadcast each record's values down its output run (the XLA
     join path's expansion; the kernel pipeline's lives in
@@ -201,7 +201,9 @@ def _expand_records(S, recs: dict, out_capacity: int, j, cfg,
 
     The Pallas record-expand (``cfg.expand``; non-f64 columns only)
     replaces all three with the streaming one-hot-matmul kernel of
-    ops/expand_pallas.py. This path is reached on TPU only when
+    ops/expand_pallas.py, which computes only the output blocks below
+    ``total`` (the slots the records cover; the rest are masked by the
+    caller). This path is reached on TPU only when
     _kernel_path_ok rejected the full pipeline (f64 columns route to
     the scatter below instead; oversized blocks still benefit here).
     """
@@ -221,7 +223,7 @@ def _expand_records(S, recs: dict, out_capacity: int, j, cfg,
         if all(v is not None for v in lanes.values()):
             names = list(lanes)
             rec_outs, start_b = expand_gather(
-                S, [lanes[nm] for nm in names], out_capacity,
+                S, [lanes[nm] for nm in names], out_capacity, total,
                 block=cfg.block, interpret=interpret,
                 name_prefix=kernel_prefix,
             )
@@ -524,7 +526,7 @@ def _join_kernel_path(build, probe, keys, b1d, b2d, p1d, p2d,
         if pack_names:
             def _kernel(_):
                 return expand_gather(
-                    S, cols_list, out_capacity, block=cfg.block,
+                    S, cols_list, out_capacity, total, block=cfg.block,
                     interpret=interpret, lo=lo_rec, build_cols=pack,
                     window=cfg.window, name_prefix=kernel_prefix,
                 )
@@ -532,7 +534,7 @@ def _join_kernel_path(build, probe, keys, b1d, b2d, p1d, p2d,
             def _fallback(_):
                 outs2, sb2 = expand_gather(
                     S, cols_list + [compacted["__lo"]], out_capacity,
-                    block=cfg.block, interpret=interpret,
+                    total, block=cfg.block, interpret=interpret,
                     name_prefix=kernel_prefix,
                 )
                 rank2 = outs2[-1].astype(jnp.int32) + (j - sb2)
@@ -548,7 +550,7 @@ def _join_kernel_path(build, probe, keys, b1d, b2d, p1d, p2d,
             build_vals_u64 = dict(zip(pack_names, build_outs))
         else:
             rec_outs, start_b = expand_gather(
-                S, cols_list, out_capacity, block=cfg.block,
+                S, cols_list, out_capacity, total, block=cfg.block,
                 interpret=interpret, name_prefix=kernel_prefix,
             )
             build_vals_u64 = {}
@@ -961,8 +963,8 @@ def sort_merge_inner_join(
         #    _join_kernel_path; this path serves CPU, f64 columns, and
         #    blocks past the f32-exact rank range.
         j = jnp.arange(out_capacity, dtype=jnp.int32)
-        out_vals, start_b = _expand_records(S, recs, out_capacity, j, cfg,
-                                            kernel_prefix)
+        out_vals, start_b = _expand_records(S, recs, out_capacity, j,
+                                            total, cfg, kernel_prefix)
         bm = pm = None
         if join_type != "inner":
             bm = out_vals.pop("__bm") != 0

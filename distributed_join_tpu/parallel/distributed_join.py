@@ -99,6 +99,21 @@ def _record_partition(tape, pt, capacity: int) -> None:
         tape.add("gathered_columns", pt.gathered_columns)
 
 
+def _record_expand(tape, total, out_capacity: int, kernel_config) -> None:
+    """One local join's expand-kernel grid (docs/OBSERVABILITY.md),
+    where a tape is given: the blocks that start below the join's match
+    total, which the kernels compute, and the static grid over its
+    output capacity (ops/expand_pallas.py ``expand_blocks``)."""
+    if tape is not None:
+        from distributed_join_tpu.ops.expand_pallas import expand_blocks
+
+        live, grid = expand_blocks(
+            total, out_capacity,
+            kernel_config.block if kernel_config is not None else None)
+        tape.add("live_blocks", live)
+        tape.add("grid_blocks", grid)
+
+
 def _varwidth_cols(table: Table) -> list:
     """ALL 2-D uint8 columns with a '<name>#len' companion and
     4-aligned width — the columns the ragged shuffle ships
@@ -634,9 +649,9 @@ def make_join_step(
                     probe_local, probe_local.valid & is_hh_p,
                     hh_probe_cap, kernel_config=kernel_config,
                 )
+                hh_out_cap = hh_out_capacity or max(p_rows // 4, 1024)
                 hh_res = sort_merge_inner_join(
-                    hh_build, hh_probe, keys_eff,
-                    hh_out_capacity or max(p_rows // 4, 1024),
+                    hh_build, hh_probe, keys_eff, hh_out_cap,
                     build_payload=bpay, probe_payload=ppay,
                     kernel_config=kernel_config,
                     _internal=sk_names, kernel_prefix="hh_",
@@ -647,12 +662,16 @@ def make_join_step(
                 if tape is not None:
                     tape.add("skew.hh_matches",
                              hh_res.total.astype(jnp.int64))
+                    _record_expand(tape.scoped("hh_expand"),
+                                   hh_res.total, hh_out_cap,
+                                   kernel_config)
                 # The normal path sees neither side's HH rows.
                 build_local = Table(build_local.columns,
                                     build_local.valid & ~is_hh_b)
                 probe_local = Table(probe_local.columns,
                                     probe_local.valid & ~is_hh_p)
 
+        te = tape.scoped("expand") if tape is not None else None
         seg = 1
         if sort_mode == "segmented" and nb > 1:
             from distributed_join_tpu.ops import segmented as seg_ops
@@ -676,6 +695,7 @@ def make_join_step(
                     kernel_config=kernel_config, join_type=join_type,
                     _internal=sk_names,
                 )
+            _record_expand(te, res.total, out_cap, kernel_config)
             parts.append(res.table)
             total = total + res.total.astype(jnp.int64)
             overflow = overflow | res.overflow
@@ -780,6 +800,7 @@ def make_join_step(
                         kernel_config=kernel_config, join_type=join_type,
                         _internal=sk_names,
                     )
+                _record_expand(te, res.total, out_cap, kernel_config)
                 parts.append(res.table)
                 total = total + res.total.astype(jnp.int64)
                 overflow = overflow | ovf_b | ovf_p | res.overflow
@@ -1178,6 +1199,7 @@ def make_probe_join_step(
         parts = []
         total = jnp.int64(0)
         overflow = jnp.bool_(False)
+        te = tape.scoped("expand") if tape is not None else None
         if nb == 1:
             with jax.named_scope("join"):
                 res = sort_merge_inner_join(
@@ -1186,6 +1208,7 @@ def make_probe_join_step(
                     probe_payload=probe_payload,
                     kernel_config=kernel_config,
                 )
+            _record_expand(te, res.total, out_cap, kernel_config)
             parts.append(res.table)
             total = total + res.total.astype(jnp.int64)
             overflow = overflow | res.overflow
@@ -1209,6 +1232,7 @@ def make_probe_join_step(
                         probe_payload=probe_payload,
                         kernel_config=kernel_config,
                     )
+                _record_expand(te, res.total, out_cap, kernel_config)
                 parts.append(res.table)
                 total = total + res.total.astype(jnp.int64)
                 overflow = overflow | ovf_p | res.overflow

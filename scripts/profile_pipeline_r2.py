@@ -79,7 +79,8 @@ def pipeline(build, probe, upto: int, salt):
     if upto >= 5:
         cols_list = [comp[1], comp[2]]
         rec_outs, start_b, rank, bouts = expand_gather(
-            S, cols_list, OUT, lo=lo_rec, build_cols=pack,
+            S, cols_list, OUT, jnp.sum(sc["cnt"]), lo=lo_rec,
+            build_cols=pack,
         )
         live = [rec_outs[0], rec_outs[1], start_b, rank, bouts[0]]
     acc = jnp.int64(0)

@@ -74,8 +74,8 @@ def run_variant(name, fake_sort=False, fake_scans=False,
                     for c in cols]
         C.stream_compact = fcompact
     if fake_expand:
-        def fexpand(Sarr, cols, out_capacity, interpret=False, lo=None,
-                    build_cols=None):
+        def fexpand(Sarr, cols, out_capacity, live, interpret=False,
+                    lo=None, build_cols=None, **_kw):
             outs = [c[:out_capacity] for c in cols]
             sb = jnp.arange(out_capacity, dtype=jnp.int32)
             if build_cols is not None:

@@ -99,18 +99,26 @@ def test_stream_compact(one_chip, compact, lanes, capacity):
              *[_spec(one_chip, MERGED, jnp.uint64)] * lanes)
 
 
+def _live(one_chip):
+    """The traced live-slot count (the join's int64 match total)."""
+    return jax.ShapeDtypeStruct((), jnp.int64, sharding=one_chip)
+
+
 def test_expand_gather_fused_build(one_chip):
-    _compile(lambda S, lo, b, *cols: expand_gather(
-                 S, list(cols), OUT, lo=lo, build_cols=[b]),
+    _compile(lambda S, live, lo, b, *cols: expand_gather(
+                 S, list(cols), OUT, live, lo=lo, build_cols=[b]),
              _spec(one_chip, OUT, jnp.int32),
+             _live(one_chip),
              _spec(one_chip, OUT, jnp.int32),
              _spec(one_chip, BUILD, jnp.uint64),
              *[_spec(one_chip, OUT, jnp.uint64)] * 2)
 
 
 def test_expand_gather_plain(one_chip):
-    _compile(lambda S, *cols: expand_gather(S, list(cols), OUT),
+    _compile(lambda S, live, *cols: expand_gather(S, list(cols), OUT,
+                                                  live),
              _spec(one_chip, OUT, jnp.int32),
+             _live(one_chip),
              *[_spec(one_chip, OUT, jnp.uint64)] * 3)
 
 

@@ -198,3 +198,32 @@ def test_distributed_join_hot_key_resolves_the_skew_path(comm8):
     got = res.table.to_pandas()
     assert (got["key"] == 0).all() and (got["build_payload"] == 0).all()
     assert sorted(got["probe_payload"]) == list(range(1024))
+
+
+@pytest.mark.parametrize("out_capacity_factor", [3.0, 0.05],
+                         ids=["sparse", "overflow"])
+def test_expand_counters_count_the_live_blocks(comm8,
+                                               out_capacity_factor):
+    """The metrics tape's expand pair, per rank: the blocks below the
+    rank's match count (capped by its output capacity), which the
+    expand kernels compute, and the static grid over that capacity."""
+    from distributed_join_tpu.ops.kernel_config import KernelConfig
+
+    block = 128
+    build, probe = generate_build_probe_tables(
+        seed=11, build_nrows=4096, probe_nrows=8192, selectivity=0.3)
+    res = make_distributed_join(
+        comm8, key="key", with_metrics=True,
+        out_capacity_factor=out_capacity_factor,
+        kernel_config=KernelConfig(block=block))(build, probe)
+    out_cap = res.table.capacity // 8
+    per_rank = res.telemetry.to_dict()["per_rank"]
+    live = [-(-min(m, out_cap) // block) for m in per_rank["matches"]]
+    grid = [-(-out_cap // block)] * 8
+    assert per_rank["expand.live_blocks"] == live
+    assert per_rank["expand.grid_blocks"] == grid
+    if bool(res.overflow):
+        assert live == grid
+    else:
+        assert 0 < sum(live) < sum(grid)
+    assert bool(res.overflow) == (out_capacity_factor < 1)

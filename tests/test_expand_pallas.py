@@ -16,6 +16,20 @@ from distributed_join_tpu.ops.expand_pallas import (
 )
 
 
+# Live-slot counts around the block edges: the kernels compute only
+# the blocks that start below ``live``, and every slot below it must
+# match the reference. Values past the capacity are clipped.
+LIVE = {
+    "none": lambda block, cap: 0,
+    "one": lambda block, cap: 1,
+    "block-1": lambda block, cap: block - 1,
+    "block": lambda block, cap: block,
+    "block+1": lambda block, cap: block + 1,
+    "mid": lambda block, cap: cap // 2 + 3,
+    "all": lambda block, cap: cap,
+}
+
+
 def _make_records(rng, n_records, out_capacity, k):
     """Random run lengths covering [0, total); sentinel tail."""
     lens = rng.integers(1, 7, size=n_records)
@@ -38,28 +52,31 @@ def test_chunk_roundtrip():
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+@pytest.mark.parametrize("live", list(LIVE))
 @pytest.mark.parametrize("n_records,out_cap,k", [
     (50, 256, 1),
     (200, 1024, 3),
     (1000, 2048, 2),
 ])
-def test_expand_matches_reference(n_records, out_cap, k):
+def test_expand_matches_reference(n_records, out_cap, k, live):
     rng = np.random.default_rng(n_records)
     S, cols, total = _make_records(rng, n_records, out_cap, k)
-    got, start_b = expand_gather(S, cols, out_cap, block=128,
-                                 interpret=True)
+    n = LIVE[live](128, out_cap)
+    got, start_b = expand_gather(S, cols, out_cap, jnp.int32(n),
+                                 block=128, interpret=True)
     want = expand_gather_reference(S, cols, out_cap)
-    # only slots below total are defined (the rest are masked padding
-    # downstream); both implementations agree there
+    # only slots below live are defined (the rest are not computed and
+    # masked downstream); both implementations agree there
+    n = min(n, out_cap)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(
-            np.asarray(g)[:total], np.asarray(w)[:total]
+            np.asarray(g)[:n], np.asarray(w)[:n]
         )
     want_sb = expand_gather_reference(
         S, [S.astype(jnp.uint32).astype(jnp.uint64)], out_cap
     )[0]
     np.testing.assert_array_equal(
-        np.asarray(start_b)[:total], np.asarray(want_sb)[:total]
+        np.asarray(start_b)[:n], np.asarray(want_sb)[:n]
     )
 
 
@@ -109,6 +126,7 @@ def _make_join_records(rng, key_specs, out_cap, kb=1):
     )
 
 
+@pytest.mark.parametrize("live", list(LIVE))
 @pytest.mark.parametrize("key_specs,out_cap,block", [
     # small uniform runs
     ([(2, 3)] * 40 + [(1, 1)] * 30, 4096, 256),
@@ -124,7 +142,8 @@ def _make_join_records(rng, key_specs, out_cap, kb=1):
     # fit window 2's slack
     ([(2, 2), (2, 0), (2, 2)] * 15, None, 256),
 ])
-def test_expand_build_windows_match_oracle(key_specs, out_cap, block):
+def test_expand_build_windows_match_oracle(key_specs, out_cap, block,
+                                          live):
     import zlib
 
     from distributed_join_tpu.ops.expand_pallas import build_windows_ok
@@ -137,20 +156,24 @@ def test_expand_build_windows_match_oracle(key_specs, out_cap, block):
     )
     # the kernel's contract: exact whenever the checker passes
     assert bool(build_windows_ok(S, lo, out_cap, block=block))
+    n = LIVE[live](block, out_cap)
     rec_outs, _sb, _rank, build_outs = expand_gather(
-        S, cols, out_cap, block=block, interpret=True,
+        S, cols, out_cap, jnp.int32(n), block=block, interpret=True,
         lo=lo, build_cols=bcols,
     )
+    n = min(n, out_cap)
     want_rec = expand_gather_reference(S, cols, out_cap)
     np.testing.assert_array_equal(
-        np.asarray(rec_outs[0])[:total], np.asarray(want_rec[0])[:total]
+        np.asarray(rec_outs[0])[:n], np.asarray(want_rec[0])[:n]
     )
     # rank/start_b are in-kernel quantities now (placeholder outputs);
-    # the build values below being exact implies the ranks were.
+    # the build values below being exact implies the ranks were (the
+    # oracle's ranks stop at the total).
+    nb = min(n, total)
     for bo, bc in zip(build_outs, bcols):
         np.testing.assert_array_equal(
-            np.asarray(bo)[:total],
-            np.asarray(bc)[rank_want[:total]],
+            np.asarray(bo)[:nb],
+            np.asarray(bc)[rank_want[:nb]],
         )
 
 
@@ -198,11 +221,17 @@ def test_join_level_gap_data_falls_back_exact(monkeypatch):
 
 
 @pytest.mark.slow
-def test_join_kernel_path_fallback_branch_exact(monkeypatch):
+@pytest.mark.parametrize("selectivity,out_cap", [
+    (0.6, 40_000),
+    (0.02, 40_000),   # ~2% of the output filled, as on TPC-H Q3
+], ids=["dense", "sparse"])
+def test_join_kernel_path_fallback_branch_exact(monkeypatch, selectivity,
+                                                out_cap):
     """Force build_windows_ok False so the lax.cond in
     _join_kernel_path takes the XLA-gather fallback branch (the
     matched-rank pipeline makes the checker pass by construction, so
-    nothing else covers that closure) and compare against pandas."""
+    nothing else covers that closure) and compare against pandas; the
+    sparse case leaves most of the expand grid dead."""
     monkeypatch.setenv("DJTPU_PALLAS_EXPAND", "1")
     import jax.numpy as jnp
     import pandas as pd
@@ -219,9 +248,9 @@ def test_join_kernel_path_fallback_branch_exact(monkeypatch):
     )
     build, probe = generate_build_probe_tables(
         seed=21, build_nrows=3000, probe_nrows=5000,
-        rand_max=1024, selectivity=0.6,
+        rand_max=1024, selectivity=selectivity,
     )
-    res = sort_merge_inner_join(build, probe, "key", 40_000)
+    res = sort_merge_inner_join(build, probe, "key", out_cap)
     merged = build.to_pandas().merge(probe.to_pandas(), on="key")
     assert int(res.total) == len(merged) > 0
     got = res.table.to_pandas().sort_values(
@@ -246,9 +275,11 @@ def test_expand_truncated_overflow_build_path():
     m = int(keep.sum())
     S_t = np.where(np.arange(S.shape[0]) < m, np.asarray(S), 2**31 - 1)
     lo_t = np.where(np.arange(S.shape[0]) < m, np.asarray(lo), 0)
+    # live is the match total, past the capacity: every slot is live
     rec_outs, _sb, _rank, build_outs = expand_gather(
-        jnp.asarray(S_t), cols, out_cap, block=256, interpret=True,
-        lo=jnp.asarray(lo_t), build_cols=bcols,
+        jnp.asarray(S_t), cols, out_cap, jnp.int64(total_full),
+        block=256, interpret=True, lo=jnp.asarray(lo_t),
+        build_cols=bcols,
     )
     np.testing.assert_array_equal(
         np.asarray(build_outs[0]),
@@ -259,15 +290,22 @@ def test_expand_truncated_overflow_build_path():
 def test_expand_empty():
     S = jnp.full((16,), 2**31 - 1, jnp.int32)
     cols = [jnp.zeros((16,), jnp.uint64)]
-    out, _ = expand_gather(S, cols, 64, block=64, interpret=True)
+    out, _ = expand_gather(S, cols, 64, jnp.int32(0), block=64,
+                           interpret=True)
     assert out[0].shape == (64,)
 
 
 @pytest.mark.slow
-def test_join_level_pallas_path_matches_oracle(monkeypatch):
+@pytest.mark.parametrize("selectivity,out_cap", [
+    (0.5, 32768),
+    (0.02, 32768),   # ~2% of the output filled, as on TPC-H Q3
+], ids=["dense", "sparse"])
+def test_join_level_pallas_path_matches_oracle(monkeypatch, selectivity,
+                                               out_cap):
     """The join-level wiring of the kernel (u64 lane encode/decode per
     dtype, the __lo geometry lane, start_b riding as the S lane) — CPU
-    CI otherwise never takes this path (use_pallas defaults off there)."""
+    CI otherwise never takes this path (use_pallas defaults off there).
+    The sparse case leaves most of the expand grid dead."""
     monkeypatch.setenv("DJTPU_PALLAS_EXPAND", "1")
     from distributed_join_tpu.ops.join import sort_merge_inner_join
     from distributed_join_tpu.utils.generators import (
@@ -276,7 +314,7 @@ def test_join_level_pallas_path_matches_oracle(monkeypatch):
 
     build, probe = generate_build_probe_tables(
         seed=5, build_nrows=2048, probe_nrows=4096,
-        rand_max=512, selectivity=0.5,
+        rand_max=512, selectivity=selectivity,
     )
     # mixed payload dtypes to exercise the lane round-trips
     build = type(build)(
@@ -285,7 +323,7 @@ def test_join_level_pallas_path_matches_oracle(monkeypatch):
          "bf32": (build.columns["build_payload"] % 97).astype(jnp.float32)},
         build.valid,
     )
-    res = sort_merge_inner_join(build, probe, "key", 32768)
+    res = sort_merge_inner_join(build, probe, "key", out_cap)
     bp, pp = build.to_pandas(), probe.to_pandas()
     merged = bp.merge(pp, on="key")
     assert int(res.total) == len(merged) > 0
@@ -297,11 +335,16 @@ def test_join_level_pallas_path_matches_oracle(monkeypatch):
     pd.testing.assert_frame_equal(got[want.columns], want)
 
 
-def test_build_path_output_tiling_exact(monkeypatch):
+@pytest.mark.parametrize("live", [336, 300, 200, 0],
+                         ids=["all", "in_tile_2", "tile_2_dead",
+                              "none"])
+def test_build_path_output_tiling_exact(monkeypatch, live):
     """Force the tiled output path (per-tile f32 budget shrunk so the
     small test splits into several tiles) and require bit-exactness vs
     the monolithic run — the spec-scale OOM fix must not change a
-    single value (round 4)."""
+    single value (round 4). Two one-block tiles; the live bound falls
+    at their end, inside the second, or before it (a whole tile
+    dead)."""
     import zlib
 
     import distributed_join_tpu.ops.expand_pallas as E
@@ -313,41 +356,95 @@ def test_build_path_output_tiling_exact(monkeypatch):
         rng, key_specs, out_cap, kb=2
     )
     assert bool(build_windows_ok(S, lo, out_cap, block=256))
-    whole = expand_gather(S, cols, out_cap, block=256, interpret=True,
-                          lo=lo, build_cols=bcols)
+    whole = expand_gather(S, cols, out_cap, jnp.int32(live), block=256,
+                          interpret=True, lo=lo, build_cols=bcols)
     monkeypatch.setattr(E, "_FUSED_TILE_BYTES", 256 * 64)  # few blocks
-    tiled = expand_gather(S, cols, out_cap, block=256, interpret=True,
-                          lo=lo, build_cols=bcols)
+    tiled = expand_gather(S, cols, out_cap, jnp.int32(live), block=256,
+                          interpret=True, lo=lo, build_cols=bcols)
     for a, b in zip(whole[0] + whole[3], tiled[0] + tiled[3]):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a)[:live],
+                                      np.asarray(b)[:live])
+    for bo, bc in zip(tiled[3], bcols):
+        np.testing.assert_array_equal(
+            np.asarray(bo)[:live], np.asarray(bc)[rank_want[:live]])
 
 
-def test_nonbuild_path_output_tiling_exact(monkeypatch):
+@pytest.mark.parametrize("tile_bytes", [128 * 32, 128 * 32 * 4],
+                         ids=["1_block_tiles", "4_block_tiles"])
+@pytest.mark.parametrize("live", [2048, 178, 100],
+                         ids=["all", "in_tile_2", "tiles_dead"])
+def test_nonbuild_path_output_tiling_exact(monkeypatch, live,
+                                           tile_bytes):
     """Same exactness contract for the NON-build wrapper (the lax.cond
     fallback branch): its gate admits out_capacity up to 2^31-2, so it
     needs the same output tiling (ADVICE r4) — and tiling must not
-    change a value or a start_b."""
+    change a value or a start_b below the live bound, which falls at
+    the end, inside the second 128-slot block, or inside the first
+    (every later tile dead)."""
     import distributed_join_tpu.ops.expand_pallas as E
 
     rng = np.random.default_rng(7)
     S, cols, total = _make_records(rng, 900, 2048, 2)
-    whole, whole_sb = expand_gather(S, cols, 2048, block=128,
+    n = jnp.int32(live)
+    whole, whole_sb = expand_gather(S, cols, 2048, n, block=128,
                                     interpret=True)
-    monkeypatch.setattr(E, "_FUSED_TILE_BYTES", 128 * 32)  # few blocks
-    tiled, tiled_sb = expand_gather(S, cols, 2048, block=128,
+    want = expand_gather_reference(S, cols, 2048)
+    for a, b in zip(whole, want):
+        np.testing.assert_array_equal(np.asarray(a)[:live],
+                                      np.asarray(b)[:live])
+    monkeypatch.setattr(E, "_FUSED_TILE_BYTES", tile_bytes)
+    tiled, tiled_sb = expand_gather(S, cols, 2048, n, block=128,
                                     interpret=True)
     for a, b in zip(whole, tiled):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a)[:live],
+                                      np.asarray(b)[:live])
     np.testing.assert_array_equal(
-        np.asarray(whole_sb)[:total], np.asarray(tiled_sb)[:total]
+        np.asarray(whole_sb)[:live], np.asarray(tiled_sb)[:live]
     )
     # Also cover the u64-S-lane start_b branch under tiling (real
     # trigger is out_capacity >= 2^24 — force it instead).
     monkeypatch.setattr(E, "_F32_EXACT", 1)
-    tiled64, tiled64_sb = expand_gather(S, cols, 2048, block=128,
+    tiled64, tiled64_sb = expand_gather(S, cols, 2048, n, block=128,
                                         interpret=True)
     for a, b in zip(whole, tiled64):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a)[:live],
+                                      np.asarray(b)[:live])
     np.testing.assert_array_equal(
-        np.asarray(whole_sb)[:total], np.asarray(tiled64_sb)[:total]
+        np.asarray(whole_sb)[:live], np.asarray(tiled64_sb)[:live]
     )
+
+
+@pytest.mark.parametrize("live", [0, 129, 700, 1024])
+@pytest.mark.parametrize("build", [True, False],
+                         ids=["build", "nonbuild"])
+def test_dead_blocks_are_not_computed(monkeypatch, build, live):
+    """The kernels compute exactly the blocks that start below
+    ``live``: the Pallas interpreter fills an output it never writes
+    with NaN, so every dead block's raw f32 rows read NaN and every
+    live block's none."""
+    import zlib
+
+    import distributed_join_tpu.ops.expand_pallas as E
+
+    block, out_cap = 128, 1024
+    raw = []
+    for name in ("_merge_rows8", "_merge_rows"):
+        merge = getattr(E, name)
+        monkeypatch.setattr(
+            E, name,
+            lambda out, k, _m=merge: raw.append(out) or _m(out, k))
+    rng = np.random.default_rng(zlib.crc32(b"dead"))
+    if build:
+        S, lo, cols, bcols, _, _ = _make_join_records(
+            rng, [(3, 2)] * 200, out_cap)
+        expand_gather(S, cols, out_cap, jnp.int32(live), block=block,
+                      interpret=True, lo=lo, build_cols=bcols)
+    else:
+        S, cols, _ = _make_records(rng, 400, out_cap, 1)
+        expand_gather(S, cols, out_cap, jnp.int32(live), block=block,
+                      interpret=True)
+    out = np.asarray(raw[0])
+    assert out.shape[1] == out_cap
+    n_live = -(-live // block) * block
+    assert not np.isnan(out[:, :n_live]).any()
+    assert np.isnan(out[:, n_live:]).all()

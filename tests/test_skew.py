@@ -171,6 +171,37 @@ def test_skew_path_agrees_with_plain_path_uniform():
     assert not bool(skewed.overflow)
 
 
+def test_hh_expand_counters_on_a_hot_key():
+    """A hot key on half the probe rows takes the heavy-hitter join;
+    its expand pair counts, per rank, the blocks below that join's
+    matches and its static grid, beside the normal path's pair."""
+    from distributed_join_tpu.ops.kernel_config import KernelConfig
+    from distributed_join_tpu.parallel.distributed_join import (
+        make_distributed_join,
+    )
+
+    comm = dj.make_communicator("tpu", n_ranks=8)
+    block, hh_out = 128, 1024
+    i = jnp.arange(8192, dtype=jnp.int64)
+    build = Table.from_dense({"key": jnp.arange(64, dtype=jnp.int64),
+                              "build_payload": jnp.arange(64) * 3})
+    probe = Table.from_dense({"key": jnp.where(i % 2 == 0, 0, i % 64),
+                              "probe_payload": i})
+    res = make_distributed_join(
+        comm, key="key", with_metrics=True, skew_threshold=0.3,
+        hh_slots=8, hh_out_capacity=hh_out, out_capacity_factor=2.0,
+        kernel_config=KernelConfig(block=block))(build, probe)
+    assert not bool(res.overflow)
+    assert int(res.total) == _oracle(build, probe)
+    per_rank = res.telemetry.to_dict()["per_rank"]
+    hh = per_rank["skew.hh_matches"]
+    assert sum(hh) == 4096
+    assert per_rank["hh_expand.live_blocks"] == [
+        -(-min(m, hh_out) // block) for m in hh]
+    assert per_rank["hh_expand.grid_blocks"] == [hh_out // block] * 8
+    assert "expand.live_blocks" in per_rank
+
+
 def test_hh_slots_exceeding_local_rows():
     """hh_slots larger than a shard must clamp, not crash (the default
     64 slots vs tiny smoke tables)."""

@@ -501,8 +501,8 @@ def test_expand_window_decouples_from_block():
     assert bool(build_windows_ok(S, lo, out_cap, block=256,
                                  window=4096))
     rec_outs, _sb, _rank, build_outs = expand_gather(
-        S, cols, out_cap, block=256, interpret=True, lo=lo,
-        build_cols=bcols, window=4096)
+        S, cols, out_cap, jnp.int32(total), block=256, interpret=True,
+        lo=lo, build_cols=bcols, window=4096)
     want_rec = expand_gather_reference(S, cols, out_cap)
     np.testing.assert_array_equal(
         np.asarray(rec_outs[0])[:total],
